@@ -1,8 +1,8 @@
 """Benchmark driver: paper tables + kernel microbenches + roofline summary.
 
 Prints one CSV block per paper table (name,us_per_call,derived columns) and
-a wall-clock microbench of every Pallas kernel (interpret mode on CPU —
-numbers validate plumbing, not TPU perf; TPU perf is the §Roofline story).
+a wall-clock microbench of every Pallas kernel (compiled on a TPU, in the
+Pallas interpreter elsewhere; each row names the device and the mode).
 Also writes machine-readable records so PRs have a compiler-perf
 trajectory to track: npec-compiled vs hand-built BERT cycle counts per
 (seq, bits) to results/npec_cycles.json, autoregressive prefill+decode
@@ -46,12 +46,16 @@ def _print_table(name: str, rows):
 
 
 def bench_kernels(quick: bool = False):
-    """Microbenchmark each Pallas kernel vs its jnp oracle (interpret)."""
+    """Microbenchmark each Pallas kernel vs its jnp oracle on the default
+    device; off a TPU the kernels run in the Pallas interpreter."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops, ref
     from repro.core import pwl
 
+    dev = jax.devices()[0]
+    where = (f"{'interpret' if ops.interpret_mode() else 'compiled'} on "
+             f"{dev.platform} ({dev.device_kind})")
     key = jax.random.PRNGKey(0)
     m, n, k = (256, 256, 256) if quick else (512, 512, 512)
     x = jax.random.normal(key, (m, k))
@@ -81,7 +85,7 @@ def bench_kernels(quick: bool = False):
             jax.block_until_ready(fn())
         us = 1e6 * (time.perf_counter() - t0) / reps
         rows.append(dict(name=name, us_per_call=round(us, 1),
-                         derived="interpret-mode-on-CPU"))
+                         derived=where))
     return rows
 
 
@@ -138,6 +142,8 @@ def main(argv=None):
                     help="length-bucketed/windowed decode cycle record "
                          "('' disables)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import init_compilation_cache
+    init_compilation_cache()
 
     from benchmarks import paper_tables
     npec_rows = decode_rows = moe_rows = serve_rows = stream_rows = None

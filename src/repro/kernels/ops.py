@@ -1,9 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 These handle quantization, padding to tile multiples, table packing, and
-dispatch.  `interpret=True` runs the kernel bodies in Python on CPU (the
-validation mode in this container); on a real TPU the same calls lower
-through Mosaic with interpret=False.
+dispatch.  `interpret` defaults to the backend: on a TPU the kernels lower
+through Mosaic; anywhere else their bodies run in the Pallas interpreter
+(the CPU validation mode).  Pass it explicitly to override, as the
+topology compile tests do (tests/test_tpu_compile.py).
 
 Models call these only when RunConfig selects the Pallas fast path; the
 jnp-level implementations in repro.core.nvu are the default (XLA-fused)
@@ -26,6 +27,14 @@ from repro.kernels import pwl_eval as _pe
 from repro.kernels import quant_matmul as _qm
 
 
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Whether a kernel call runs in the Pallas interpreter: `interpret`
+    when given, else everywhere but on a TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
 @functools.lru_cache(maxsize=None)
 def packed_table(name: str, segments: int = 16) -> jnp.ndarray:
     return _pe.pack_table(pwl.get_table(name, segments))
@@ -42,7 +51,7 @@ def _pad2(x, bm, bn, value=0.0):
 
 def pwl_activation(x: jnp.ndarray, name: str, segments: int = 16,
                    block_m: int = 256, block_n: int = 512,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """Elementwise nonlinearity via the PWL kernel (any input shape)."""
     shape = x.shape
     flat = x.reshape(-1)
@@ -53,14 +62,15 @@ def pwl_activation(x: jnp.ndarray, name: str, segments: int = 16,
     rows_p = -(-rows // bm) * bm
     x2 = jnp.pad(flat, (0, rows_p * cols - n)).reshape(rows_p, cols)
     out = _pe.pwl_eval_2d(x2, packed_table(name, segments), block_m=bm,
-                          block_n=cols, interpret=interpret)
+                          block_n=cols, interpret=interpret_mode(interpret))
     return out.reshape(-1)[:n].reshape(shape).astype(x.dtype)
 
 
 def quant_matmul(x: jnp.ndarray, w: jnp.ndarray,
                  activation: Optional[str] = None, segments: int = 16,
                  block_m: int = 256, block_n: int = 256, block_k: int = 256,
-                 out_dtype=jnp.float32, interpret: bool = True) -> jnp.ndarray:
+                 out_dtype=jnp.float32,
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """MMU matmul: int8-quantize x (per-tensor) and w (per-channel), run the
     fused kernel, return float activations (optionally PWL-activated)."""
     *lead, kdim = x.shape
@@ -76,12 +86,13 @@ def quant_matmul(x: jnp.ndarray, w: jnp.ndarray,
     out = _qm.quant_matmul(qx, qw, xq.scale.reshape(1), ws, tab,
                            out_dtype=out_dtype, block_m=bm,
                            block_n=block_n, block_k=block_k,
-                           interpret=interpret)
+                           interpret=interpret_mode(interpret))
     return out[:m0, :n0].reshape(*lead, n0)
 
 
 def softmax(x: jnp.ndarray, segments: int = 16, causal: bool = False,
-            block_rows: int = 256, interpret: bool = True) -> jnp.ndarray:
+            block_rows: int = 256,
+            interpret: Optional[bool] = None) -> jnp.ndarray:
     """Row softmax over the last axis via the NVU softmax kernel."""
     *lead, n = x.shape
     x2 = x.reshape(-1, n)
@@ -90,14 +101,15 @@ def softmax(x: jnp.ndarray, segments: int = 16, causal: bool = False,
     out = _sm.nvu_softmax_rows(xp, packed_table("exp", segments),
                                packed_table("recip", segments),
                                block_rows=br, causal=causal,
-                               interpret=interpret)
+                               interpret=interpret_mode(interpret))
     return out[:m0].reshape(*lead, n)
 
 
 def layernorm(x: jnp.ndarray, gamma: jnp.ndarray,
               beta: Optional[jnp.ndarray] = None, eps: float = 1e-5,
               segments: int = 16, rms_only: bool = False,
-              block_rows: int = 0, interpret: bool = True) -> jnp.ndarray:
+              block_rows: int = 0,
+              interpret: Optional[bool] = None) -> jnp.ndarray:
     """LayerNorm/RMSNorm over the last axis via the NVU layernorm kernel."""
     *lead, n = x.shape
     x2 = x.reshape(-1, n)
@@ -108,12 +120,12 @@ def layernorm(x: jnp.ndarray, gamma: jnp.ndarray,
     out = _ln.nvu_layernorm_rows(xp, gamma, beta,
                                  packed_table("rsqrt", segments), eps=eps,
                                  block_rows=block_rows, rms_only=rms_only,
-                                 interpret=interpret)
+                                 interpret=interpret_mode(interpret))
     return out[:m0].reshape(*lead, n)
 
 
 def rmsnorm(x, gamma, eps: float = 1e-6, segments: int = 16,
-            interpret: bool = True):
+            interpret: Optional[bool] = None):
     return layernorm(x, gamma, None, eps=eps, segments=segments,
                      rms_only=True, interpret=interpret)
 
@@ -121,7 +133,7 @@ def rmsnorm(x, gamma, eps: float = 1e-6, segments: int = 16,
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     scale: Optional[float] = None, use_pwl: bool = True,
                     segments: int = 16, block_q: int = 256,
-                    block_kv: int = 256, interpret: bool = True):
+                    block_kv: int = 256, interpret: Optional[bool] = None):
     """Flash attention with NVU (PWL) softmax.  Shapes must tile evenly;
     decode (sq != skv) runs with causal=False over the visible cache."""
     sq, skv = q.shape[2], k.shape[2]
@@ -131,4 +143,4 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                                packed_table("recip", segments),
                                causal=causal, window=window, scale=scale,
                                use_pwl=use_pwl, block_q=bq, block_kv=bkv,
-                               interpret=interpret)
+                               interpret=interpret_mode(interpret))

@@ -47,6 +47,11 @@ For encoder-only BERT, "serving" is one encoder pass per request batch —
 see examples/serve_bert.py, which reproduces the paper's latency table
 with the NPE cycle model alongside wall-clock CPU numbers.
 
+Every backend builds ``--arch`` at its published width; ``--smoke`` swaps
+in the reduced same-family config and the tiny CI workload.  Full
+``glm4_9b`` (the default arch, ~19 GB in bf16) does not fit one 16 GB
+TPU v5e: serve it with ``--smoke`` or pick ``--arch bert_base``.
+
 CI smoke: PYTHONPATH=src python -m repro.launch.serve --backend npec --smoke
 """
 from __future__ import annotations
@@ -64,6 +69,7 @@ import numpy as np
 from repro.config import MeshConfig, RunConfig, ShapeConfig
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticRequests
+from repro.launch.compile_cache import init_compilation_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_decode_step
 from repro.models import common as cm
@@ -211,7 +217,7 @@ def run_npec_fleet(args) -> Dict[str, float]:
     from repro.core.overlay import NPEHardware
     from repro.npec.fleet import NPEFleet
 
-    cfg = get_config(args.arch, smoke=True)
+    cfg = get_config(args.arch, smoke=args.smoke)
     if args.shard == "tensor" and args.overlays > 1:
         for dim, what in ((cfg.num_heads, "attention heads"),
                           (cfg.num_kv_heads, "kv heads"),
@@ -263,13 +269,18 @@ def run_npec_fleet(args) -> Dict[str, float]:
     return report
 
 
-def run_npec(args) -> Dict[str, float]:
+def run_npec(args):
     """Compiled-stream serving: NPEEngine over the synthetic workload;
-    latency/throughput from compiled-stream cycle counts."""
+    latency/throughput from compiled-stream cycle counts.
+
+    Returns ``(report, engine)``: the printed report and the engine that
+    served it, whose ``stats.requests`` carry the generated tokens and
+    whose ``params`` are the weights they were computed from (the seeded
+    random init), so a caller can check the served tokens."""
     from repro.core.overlay import NPEHardware
     from repro.npec.runtime import NPEEngine
 
-    cfg = get_config(args.arch, smoke=True)
+    cfg = get_config(args.arch, smoke=args.smoke)
     if args.dtype_float32:
         import dataclasses
         cfg = dataclasses.replace(cfg, dtype="float32")
@@ -303,10 +314,12 @@ def run_npec(args) -> Dict[str, float]:
           f"{args.cycle_model} cycle model):")
     _print_report(report)
     _npec_outputs(args, tracer, snapshot)
-    return report
+    return report, engine
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The CLI's arguments, with `--seq-buckets` parsed and the
+    `--smoke` workload applied."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4_9b")
     ap.add_argument("--backend", choices=("jnp", "npec"), default="jnp")
@@ -372,7 +385,8 @@ def main(argv=None):
     ap.add_argument("--dtype-float32", action="store_true",
                     help="npec: force float32 params (test parity)")
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny workload (CI): 2 slots, 4 requests, 4 tokens")
+                    help="reduced same-family config and a tiny workload "
+                         "(CI): 2 slots, 4 requests, 4 tokens")
     args = ap.parse_args(argv)
     if args.seq_buckets and args.seq_buckets != "auto":
         args.seq_buckets = tuple(
@@ -380,6 +394,12 @@ def main(argv=None):
     if args.smoke:
         args.batch, args.requests, args.gen = 2, 4, 4
         args.capacity = min(args.capacity, 24)
+    return args
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    init_compilation_cache()
     if args.backend == "npec":
         if (args.overlays, args.shard, args.rate) == (1, "replicate", None):
             run_npec(args)      # lone-engine path (honors --prefill-chunk)
@@ -387,7 +407,8 @@ def main(argv=None):
             run_npec_fleet(args)
         print("serve OK")
         return
-    srv = Server(args.arch, smoke=True, batch=args.batch, npe=args.npe)
+    srv = Server(args.arch, smoke=args.smoke, batch=args.batch,
+                 npe=args.npe)
     reqs = SyntheticRequests(srv.cfg.vocab_size, max_prompt=16)
     prompts = [reqs.request(i) for i in range(args.batch)]
     stats = srv.generate(prompts, gen_tokens=args.gen)

@@ -88,7 +88,10 @@ def tile_matmul(hw: NPEHardware, n: int, k: int, m: int,
     assert tiled == row_tiles * k_tiles * m
     return dict(row_tiles=row_tiles, k_tiles=k_tiles, cols=m,
                 tiles=row_tiles * k_tiles, tiled_cycles=tiled,
-                ideal_cycles=ideal, efficiency=ideal / tiled)
+                ideal_cycles=ideal,
+                # an empty column shard (fewer columns than overlays)
+                # pads nothing
+                efficiency=ideal / tiled if tiled else 1.0)
 
 
 def tile_stream(tiling: Dict[str, Any]) -> Dict[str, int]:

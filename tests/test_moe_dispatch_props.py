@@ -11,10 +11,8 @@ MoE block (`npec.trace_moe_block` executed functionally) reproduces
     the combine output matches `moe.apply` (dropped slots contribute
     zero, gates NOT renormalized after the drop).
 
-Hypothesis drives the draws when installed (guarded via
-tests/_hypothesis_compat.py, like tests/test_kernels.py); the
-deterministic sweep below exercises the same properties on fixed corner
-draws either way (the seed image ships without hypothesis).
+Hypothesis drives the random draws; the deterministic sweep below
+exercises the same properties on fixed corner draws.
 """
 import dataclasses
 
@@ -23,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro import npec
 from repro.config import MoEConfig
 from repro.configs import get_config
@@ -116,7 +114,7 @@ def test_dispatch_matches_moe_apply_random(E, k_raw, S, cf, sigmoid, seed):
     _assert_dispatch_semantics(cfg, S, seed)
 
 
-# --- deterministic sweep (runs with or without hypothesis) -----------------
+# --- deterministic sweep: fixed corner draws --------------------------------
 
 SWEEP = [
     # (E, k, S, capacity_factor, router_act)

@@ -33,7 +33,7 @@ import pytest
 from repro import npec
 from repro.core.overlay import NPEHardware
 from repro.data.pipeline import SyntheticRequests
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.npec.fleet import (NPEFleet, partition_expert,
                               partition_pipeline, partition_tensor)
 from repro.npec.runtime import NPEEngine

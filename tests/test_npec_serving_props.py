@@ -1,8 +1,8 @@
 """Property-test suite for the serving stack (chunked prefill +
 prefill/decode disaggregation, docs/serving.md + docs/fleet.md).
 
-Hypothesis properties (skipped when hypothesis is absent — the
-deterministic sweeps below cover the same gates so CI never goes dark):
+Hypothesis properties (the deterministic sweeps below cover the same
+gates on fixed draws):
 
   * chunked-prefill equivalence — for random prompt lengths S and chunk
     sizes in {1..S}, running ceil(S/chunk) causal cache slices seeds the
@@ -21,7 +21,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import npec
 from repro.configs import get_config
