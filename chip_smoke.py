@@ -18,8 +18,9 @@ two phases:
      prefill logits from the chip must agree to NPE_LOGITS_TOL with the
      same compiled stream executed on the host CPU.
 
-Without a TPU it exits nonzero before serving anything.  Wall times on
-the earlier lines come from one run and are smoke timings, not metrics.
+Without a TPU it exits nonzero before serving anything.  It times
+nothing: the benchmark (`bench/run.py`) measures the served path, and the
+program's own spans (`repro.npec.obs.spans`) say where its time goes.
 The last line of standard output is the JSON result.
 
 Usage, from the repository root: python3 chip_smoke.py
@@ -29,7 +30,6 @@ from __future__ import annotations
 import contextlib
 import json
 import sys
-import time
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List
@@ -81,58 +81,28 @@ class Prefill:
 
 @dataclass
 class Run:
-    """One `run_npec` call as the smoke observed it.  Every time is host
-    wall time taken after `jax.block_until_ready` on what the timed call
-    produced."""
+    """One `run_npec` call: its engine and every prefill it executed."""
     engine: Any = None
-    wall_s: float = 0.0
-    setup_s: float = 0.0                  # NPEEngine(): trace/lower/schedule
-    prefill_s: List[float] = field(default_factory=list)
-    decode_s: List[float] = field(default_factory=list)
     prefills: List[Prefill] = field(default_factory=list)
 
 
 @contextlib.contextmanager
 def _observe(run: Run):
-    """Time engine setup, every prefill and every decode step of the
-    engine built inside, and keep each prefill's stream, feeds and
-    logits."""
-    orig_init = engine_mod.NPEEngine.__init__
+    """Keep each prefill's stream, feeds and logits of the engine built
+    inside."""
     orig_execute = engine_mod.execute
-    orig_step = npec.DecodeSession.step
-
-    def init(eng, *a, **kw):
-        t0 = time.perf_counter()
-        orig_init(eng, *a, **kw)
-        if eng.session is not None:
-            jax.block_until_ready(eng.session.caches)
-        run.setup_s += time.perf_counter() - t0
 
     def execute(program, params, feeds, **kw):
-        t0 = time.perf_counter()
         res = orig_execute(program, params, feeds, **kw)
-        jax.block_until_ready((res.outputs, res.kv_exports))
-        run.prefill_s.append(time.perf_counter() - t0)
         run.prefills.append(Prefill(program, dict(feeds), kw.get("cfg"),
                                     np.asarray(res[0])))
         return res
 
-    def step(sess, *a, **kw):
-        t0 = time.perf_counter()
-        out = orig_step(sess, *a, **kw)
-        jax.block_until_ready((out, sess.caches))
-        run.decode_s.append(time.perf_counter() - t0)
-        return out
-
-    engine_mod.NPEEngine.__init__ = init
     engine_mod.execute = execute
-    npec.DecodeSession.step = step
     try:
         yield run
     finally:
-        engine_mod.NPEEngine.__init__ = orig_init
         engine_mod.execute = orig_execute
-        npec.DecodeSession.step = orig_step
 
 
 def serve_once(argv) -> Run:
@@ -140,9 +110,7 @@ def serve_once(argv) -> Run:
     args = serve.parse_args(list(argv))
     run = Run()
     with _observe(run):
-        t0 = time.perf_counter()
         _, run.engine = serve.run_npec(args)
-        run.wall_s = time.perf_counter() - t0
     if len(run.prefills) != len(run.engine.stats.requests):
         raise RuntimeError(
             f"observed {len(run.prefills)} prefills for "
@@ -159,12 +127,6 @@ def _describe(tag: str, run: Run) -> None:
           f"slots={eng.slots} capacity={eng.capacity} "
           f"requests={len(eng.stats.requests)} served_tokens={served} "
           f"prompt_lengths={[len(r.prompt) for r in eng.stats.requests]}")
-    decode = np.asarray(run.decode_s)
-    print(f"[{tag}] smoke timings, one run, not metrics: "
-          f"run_npec {run.wall_s:.3f} s; setup {run.setup_s:.3f} s; "
-          f"first prefill {run.prefill_s[0]:.3f} s; "
-          f"mean decode step {decode.mean():.4f} s over {decode.size} "
-          f"steps (first {decode[0]:.3f} s)")
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     print(f"[{tag}] peak device memory in use: "
           f"{'not reported' if peak is None else f'{peak} bytes'}")

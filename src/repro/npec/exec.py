@@ -23,7 +23,9 @@ Semantics mirror the jnp modules op-for-op:
 
 Buffers live in a node-indexed environment and are freed at last use —
 the executor reports the resulting peak live footprint, the quantity the
-overlay's MMEM has to cover (paper §5.2).
+overlay's MMEM has to cover (paper §5.2).  Each call, each node's
+dispatch (named by its op class) and each `DecodeSession` bank update
+opens a wall-clock span (repro.npec.obs.spans).
 
 Decode streams execute *statefully* through `DecodeSession`: the KV caches
 (`cache` nodes) feed in as persistent MMEM-resident buffers, each step's
@@ -50,6 +52,9 @@ from repro.core.quant import dense_maybe_quant
 from repro.models import common as cm
 from repro.npec.ir import FOLDED_OPS, Graph, Node
 from repro.npec.lower import CompiledProgram
+from repro.npec.obs.spans import (EXEC_EXECUTE, SESSION_LOAD_SLOT,
+                                  SESSION_MIGRATE, SESSION_RESET_SLOT,
+                                  node_span, span)
 
 
 @dataclass
@@ -246,6 +251,21 @@ def _nbytes(x) -> int:
     return int(x.size) * x.dtype.itemsize
 
 
+def _use_counts(graph: Graph) -> Dict[int, int]:
+    """Reads of each node's value; a buffer is freed at its last read."""
+    uses = {n.id: 0 for n in graph.nodes}
+    for n in graph.nodes:
+        for i in n.inputs:
+            uses[i] += 1
+    for o in graph.outputs:
+        uses[o] += 1                            # outputs never freed
+    for nid in graph.cache_updates.values():
+        uses[nid] += 1                          # carried into the next step
+    for nid in graph.kv_exports.values():
+        uses[nid] += 1                          # handed to load_slot
+    return uses
+
+
 def execute(program: Union[CompiledProgram, Graph], params: Any,
             feeds: Dict[str, Any], *, cfg: Optional[ModelConfig] = None,
             npe_quant: bool = False, bits: int = 8, use_pwl: bool = False,
@@ -270,17 +290,6 @@ def execute(program: Union[CompiledProgram, Graph], params: Any,
                 else None)
 
     env: Dict[int, jnp.ndarray] = {}
-    uses = {n.id: 0 for n in graph.nodes}
-    for n in graph.nodes:
-        for i in n.inputs:
-            uses[i] += 1
-    for o in graph.outputs:
-        uses[o] += 1                            # outputs never freed
-    for nid in graph.cache_updates.values():
-        uses[nid] += 1                          # carried into the next step
-    for nid in graph.kv_exports.values():
-        uses[nid] += 1                          # handed to load_slot
-
     live = 0
     peak = 0
     mask_memo: Dict[Any, jnp.ndarray] = {}   # per-call dispatch-mask cache
@@ -300,7 +309,7 @@ def execute(program: Union[CompiledProgram, Graph], params: Any,
             del env[nid]
         return val
 
-    for node in graph.nodes:
+    def run(node: Node) -> None:
         op = node.op
         if op == "input":
             x = jnp.asarray(feeds[node.attrs["name"]])
@@ -414,6 +423,12 @@ def execute(program: Union[CompiledProgram, Graph], params: Any,
                 put(node.id, x[..., i:i + 1, :])
         else:
             raise NotImplementedError(f"executor has no rule for {op!r}")
+
+    with span(EXEC_EXECUTE, nodes=len(graph.nodes)):
+        uses = _use_counts(graph)
+        for node in graph.nodes:
+            with span(node_span(graph, node)):
+                run(node)
 
     return ExecResult([env[o] for o in graph.outputs], peak, n_instrs,
                       {name: env[nid]
@@ -550,9 +565,10 @@ class DecodeSession:
         """Recycle one slot: zero its cache banks and position counter."""
         self._check_slot(slot)
         key = f".slot{slot}."
-        for name in self.caches:
-            if key in name:
-                self.caches[name] = jnp.zeros_like(self.caches[name])
+        with span(SESSION_RESET_SLOT, slot=slot):
+            for name in self.caches:
+                if key in name:
+                    self.caches[name] = jnp.zeros_like(self.caches[name])
         self.pos[slot] = 0
 
     def load_slot(self, slot: int, kv: Dict[str, jnp.ndarray],
@@ -566,15 +582,18 @@ class DecodeSession:
             raise ValueError(
                 f"prefill of {n_tokens} tokens exceeds the compiled cache "
                 f"capacity {self.capacity}")
-        self.reset_slot(slot)
-        for name, rows in kv.items():
-            base, leaf = name.rsplit(".", 1)
-            bank = f"{base}.slot{slot}.{leaf}"
-            if bank not in self.caches:
-                raise KeyError(f"no cache bank {bank!r} for export {name!r}")
-            arr = jnp.asarray(rows, jnp.float32)
-            arr = arr.reshape(arr.shape[-2:])       # drop any lead axes
-            self.caches[bank] = self.caches[bank].at[: arr.shape[0]].set(arr)
+        with span(SESSION_LOAD_SLOT, slot=slot, rows=n_tokens):
+            self.reset_slot(slot)
+            for name, rows in kv.items():
+                base, leaf = name.rsplit(".", 1)
+                bank = f"{base}.slot{slot}.{leaf}"
+                if bank not in self.caches:
+                    raise KeyError(
+                        f"no cache bank {bank!r} for export {name!r}")
+                arr = jnp.asarray(rows, jnp.float32)
+                arr = arr.reshape(arr.shape[-2:])   # drop any lead axes
+                self.caches[bank] = (
+                    self.caches[bank].at[: arr.shape[0]].set(arr))
         self.pos[slot] = n_tokens
 
     # --- bucket migration (length-bucketed serving) ------------------------
@@ -605,20 +624,21 @@ class DecodeSession:
                 f"position(s) reach {deepest}")
         moved = 0
         caches: Dict[str, jnp.ndarray] = {}
-        for name, nid in graph.caches.items():
-            old = self.caches[name]
-            shape = graph.node(nid).shape
-            lead = old.shape[:len(old.shape) - len(shape)]
-            if self.batched:
-                live = self._bank_live_rows(name)
-            else:
-                live = deepest
-            n = min(live, old.shape[-2], shape[-2])
-            buf = jnp.zeros(lead + shape, jnp.float32)
-            if n:
-                buf = buf.at[..., :n, :].set(old[..., :n, :])
-            caches[name] = buf
-            moved += n
+        with span(SESSION_MIGRATE, capacity=new_capacity):
+            for name, nid in graph.caches.items():
+                old = self.caches[name]
+                shape = graph.node(nid).shape
+                lead = old.shape[:len(old.shape) - len(shape)]
+                if self.batched:
+                    live = self._bank_live_rows(name)
+                else:
+                    live = deepest
+                n = min(live, old.shape[-2], shape[-2])
+                buf = jnp.zeros(lead + shape, jnp.float32)
+                if n:
+                    buf = buf.at[..., :n, :].set(old[..., :n, :])
+                caches[name] = buf
+                moved += n
         self.caches = caches
         self.compiled = compiled
         self.capacity = new_capacity

@@ -67,6 +67,8 @@ from repro.npec import (CompiledProgram, DecodeSession, compile_decode,
                         compile_prefill, execute, greedy_schedule,
                         schedule_for, stream_schedule, transfer_cycles)
 from repro.npec.obs.metrics import MetricsRegistry
+from repro.npec.obs.spans import (ENGINE_ADMIT, ENGINE_DECODE, ENGINE_SYNC,
+                                  span)
 from repro.npec.obs.tracer import NULL_TRACER
 from repro.npec.runtime.batch import Request, RequestQueue, SlotPool
 from repro.npec.runtime.clock import CycleClock, LatencyTracker
@@ -549,12 +551,17 @@ class NPEEngine:
         compiled prefill (charge the stream, seed the banks, emit the
         first token).  Chunked engines only bind and enqueue the slices;
         disaggregated decode overlays charge the KV recv transfer."""
-        if self.kv_recv is not None:
-            self._admit_kv(slot, req)
-            return
-        if self.prefill_chunk is not None:
-            self._admit_chunked(slot, req)
-            return
+        with span(ENGINE_ADMIT, rid=req.rid, rows=len(req.prompt)):
+            if self.kv_recv is not None:
+                self._admit_kv(slot, req)
+            elif self.prefill_chunk is not None:
+                self._admit_chunked(slot, req)
+            else:
+                self._admit_whole(slot, req)
+
+    def _admit_whole(self, slot: int, req: Request) -> None:
+        """Whole-prompt admission: one compiled prefill seeds the slot's
+        banks and emits the first token."""
         prog = self._prefill_program(len(req.prompt))
         if self._external_queue:
             self.stats.requests.append(req)
@@ -583,7 +590,9 @@ class NPEEngine:
             res = execute(prog, self.params, {"tokens": req.prompt},
                           cfg=self._npe_cfg)
             self.session.load_slot(slot, res.kv_exports, len(req.prompt))
-            tok = int(np.argmax(np.asarray(res[0])[..., -1, :]))
+            with span(ENGINE_SYNC):
+                logits = np.asarray(res[0])
+            tok = int(np.argmax(logits[..., -1, :]))
         else:
             tok = self._synthetic_token(req)
         self.pool.bind(slot, req)
@@ -649,6 +658,12 @@ class NPEEngine:
         slot = next(iter(self._prefilling))
         st = self._prefilling[slot]
         base, rows = st.spans[st.next_i]
+        with span(ENGINE_ADMIT, rid=st.req.rid, rows=rows):
+            self._run_slice(slot, st, base, rows)
+        return True
+
+    def _run_slice(self, slot: int, st: _PrefillState, base: int,
+                   rows: int) -> None:
         prog = self._prefill_program(rows)
         t0, t1 = self._charge("prefill", prog, self._schedule_cycles(prog))
         self.stats.metrics.observe("prefill_cycles", t1 - t0)
@@ -670,13 +685,13 @@ class NPEEngine:
             feeds["pos_ids"] = np.arange(base, base + rows, dtype=np.int32)
             feeds["tokens"] = st.req.prompt[base:base + rows]
             res = execute(prog, self.params, feeds, cfg=self._npe_cfg)
-            st.caches.update({k: np.asarray(v)
-                              for k, v in res.cache_updates.items()})
-            st.logits_tail = np.asarray(res[0])
+            with span(ENGINE_SYNC):
+                st.caches.update({k: np.asarray(v)
+                                  for k, v in res.cache_updates.items()})
+                st.logits_tail = np.asarray(res[0])
         st.next_i += 1
         if st.next_i == len(st.spans):
             self._finish_prefill(slot)
-        return True
 
     def _finish_prefill(self, slot: int) -> None:
         """Last slice done: seed the decode slot from the carried banks
@@ -763,9 +778,18 @@ class NPEEngine:
                 self.tracer.req_split(rids, "allreduce", tm, t1,
                                       self.trace_overlay,
                                       bucket=self._bucket)
+        with span(ENGINE_DECODE, active=int(active.sum()),
+                  bucket=self._bucket):
+            self._decode(active)
+        return True
+
+    def _decode(self, active: np.ndarray) -> None:
+        """One batched decode pass over the `active` slots, then each
+        slot's token bookkeeping and eviction."""
         if self.numeric:
-            out = np.asarray(self.session.step(self._next_tok,
-                                               active=active))
+            out = self.session.step(self._next_tok, active=active)
+            with span(ENGINE_SYNC):
+                out = np.asarray(out)
             next_tok = np.argmax(out[..., :], axis=-1).astype(np.int32)
         else:
             next_tok = np.zeros(self.slots, np.int32)
@@ -783,7 +807,6 @@ class NPEEngine:
             self._next_tok[slot] = tok
             if not req.wants_more():
                 self._finish(slot)
-        return True
 
     def run(self) -> EngineStats:
         """Drain the queue; returns the cycle-derived stats."""
