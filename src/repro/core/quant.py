@@ -91,11 +91,6 @@ def quant_dense(x: jnp.ndarray, w: QTensor, bias: Optional[jnp.ndarray] = None,
     return out.astype(dt)
 
 
-# Wall-clock span around the weight's quantization in `dense_maybe_quant`
-# (repro.npec.obs.spans lists it with the serving path's other spans).
-QUANTIZE_WEIGHT_SPAN = "npec.exec.quantize_weight"
-
-
 def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray,
                       bias: Optional[jnp.ndarray] = None,
                       npe_quant: bool = False, bits: int = 8,
@@ -115,8 +110,7 @@ def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray,
     x2 = x.reshape(-1, k)
     if bits == 8:
         # True integer path: int8 x int8 -> int32 is exact for K <= 2^17.
-        with jax.profiler.TraceAnnotation(QUANTIZE_WEIGHT_SPAN):
-            wq = quantize(w, bits, axis=1)
+        wq = quantize(w, bits, axis=1)
         y = quant_dense(x2, wq, bias, act_bits=bits, act_axis=act_axis)
     else:
         # 16-bit MMU mode.  int16 products overflow int32 accumulators and
@@ -125,8 +119,7 @@ def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray,
         # quantization error (the quantity under study) is identical; only
         # accumulator rounding differs (f32 vs the FPGA's wide adders).
         xq = fake_quantize(x2.astype(jnp.float32), bits, axis=act_axis)
-        with jax.profiler.TraceAnnotation(QUANTIZE_WEIGHT_SPAN):
-            wq = fake_quantize(w.astype(jnp.float32), bits, axis=1)
+        wq = fake_quantize(w.astype(jnp.float32), bits, axis=1)
         y = xq @ wq
         if bias is not None:
             y = y + bias.astype(jnp.float32)

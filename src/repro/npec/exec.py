@@ -21,11 +21,26 @@ Semantics mirror the jnp modules op-for-op:
                         matmul `quantize=False` attr, exactly as the
                         reference computes them).
 
-Buffers live in a node-indexed environment and are freed at last use —
-the executor reports the resulting peak live footprint, the quantity the
-overlay's MMEM has to cover (paper §5.2).  Each call, each node's
-dispatch (named by its op class) and each `DecodeSession` bank update
-opens a wall-clock span (repro.npec.obs.spans).
+Each graph runs as ONE jitted XLA program (`executable`), memoized on
+the graph per numerics: the node loop below runs only while JAX traces
+it, and every later call is a single dispatch.  The graph and the
+numerics are fixed in the program; the weights and the feeds (tokens,
+`pos`, cache banks) are its arguments, so no weight becomes a constant
+and a new position never retraces.  A node with ops of its own runs
+through `_shared_node`, which JAX traces once per distinct node and
+input shapes and reuses for every repeat (a layer's heads, a stack's
+layers), so tracing costs the distinct nodes.  Buffers live in a
+node-indexed environment and are freed at last use — the executor
+reports the resulting peak live footprint, the quantity the overlay's
+MMEM has to cover (paper §5.2).  That bookkeeping reads only shapes and
+dtypes, so it runs at trace time and each call returns what its feed
+shapes gave.
+
+Each call and each `DecodeSession` bank update opens a wall-clock span
+(repro.npec.obs.spans); each trace opens `npec.exec.trace`.  While a
+profiler records, each call also marks its nodes, after the dispatch:
+one empty span per node named by its op class.  The node's ops carry
+the same class as a `jax.named_scope` in the compiled program.
 
 Decode streams execute *statefully* through `DecodeSession`: the KV caches
 (`cache` nodes) feed in as persistent MMEM-resident buffers, each step's
@@ -40,9 +55,12 @@ every op vectorizes over it unchanged.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Union
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Tuple,
+                    Union)
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -52,9 +70,10 @@ from repro.core.quant import dense_maybe_quant
 from repro.models import common as cm
 from repro.npec.ir import FOLDED_OPS, Graph, Node
 from repro.npec.lower import CompiledProgram
-from repro.npec.obs.spans import (EXEC_EXECUTE, SESSION_LOAD_SLOT,
-                                  SESSION_MIGRATE, SESSION_RESET_SLOT,
-                                  node_span, span)
+from repro.npec.obs.spans import (EXEC_EXECUTE, EXEC_PREFIX,
+                                  EXEC_QUANTIZE_WEIGHT, EXEC_TRACE,
+                                  SESSION_LOAD_SLOT, SESSION_MIGRATE,
+                                  SESSION_RESET_SLOT, node_class, span)
 
 
 @dataclass
@@ -74,10 +93,14 @@ class ExecResult:
         return self.outputs[i]
 
 
-def _resolve_param(params, node: Node) -> jnp.ndarray:
+def _param_leaf(params, node: Node):
     v = params
     for key in node.attrs["path"]:
         v = v[key]
+    return v
+
+
+def _slice_param(v, node: Node) -> jnp.ndarray:
     if node.attrs.get("layer") is not None:
         v = v[node.attrs["layer"]]
     if node.attrs.get("index") is not None:
@@ -176,8 +199,6 @@ def _topk(node: Node, x):
     """jax.lax.top_k over the last axis, exactly as `models/moe.apply`;
     the values node optionally renormalizes the selected gates (softmax
     routers with k > 1, via the shared `moe.renormalize_gates`)."""
-    import jax
-
     from repro.models import moe as moe_mod
 
     vals, ids = jax.lax.top_k(x, node.attrs["k"])
@@ -199,8 +220,8 @@ def _dispatch_mask(ids_flat, num_experts: int, capacity: int):
 
 def _dispatch_mask_cached(memo, key, ids_flat, num_experts, capacity):
     """The dispatch mask is needed twice per MoE layer (scatter + combine)
-    from the SAME indices node — memoize it per execute() call, keyed by
-    the ids node id."""
+    from the SAME indices node — memoize it per trace, keyed by the ids
+    node id."""
     if memo is None:
         return _dispatch_mask(ids_flat, num_experts, capacity)
     k = (key, num_experts, capacity)
@@ -247,6 +268,93 @@ def _gather_combine(node: Node, stacked, ids, gates, *, memo=None,
     return out.reshape(lead + (s, d))
 
 
+def _cache_append(node: Node, c, new, posv):
+    slot = node.attrs.get("slot")
+    if slot is not None:
+        # batched stream: row `slot` of the merged (B, hd) projection,
+        # written at this slot's own position
+        new = new[..., slot:slot + 1, :]
+        posv = posv[..., slot]
+    cap = node.shape[-2]
+    if node.attrs.get("rows"):
+        # chunked-prefill burst: write all C rows of `new` at their
+        # absolute positions posv[r].  The one-hot einsum copies each row
+        # exactly (1.0 * x plus zeros), so a chunked bank is bitwise-equal
+        # to the monolithic prefill's rows.
+        idx = posv.astype(jnp.int32)
+        onehot = (jnp.arange(cap, dtype=jnp.int32)[:, None]
+                  == idx[None, :])
+        write = jnp.einsum("cr,...rd->...cd", onehot.astype(new.dtype), new)
+        keep = ~onehot.any(axis=1)
+        return jnp.where(keep[:, None], c, write)
+    if node.attrs.get("window"):
+        # ring bank: the write wraps — the bank holds the last `cap`
+        # tokens while the position counter keeps growing
+        posv = posv % cap
+    hit = (jnp.arange(cap, dtype=jnp.int32) == posv)[:, None]
+    return jnp.where(hit, new, c)
+
+
+class _NodeKey(NamedTuple):
+    """Everything a node's value depends on besides its inputs' values."""
+    op: str
+    cls: str
+    shape: Tuple[int, ...]
+    attrs: Tuple[Tuple[str, Any], ...]   # sorted; a bank's name left out
+    weight_resident: bool
+    act_axis: Optional[int]
+    npe_quant: bool
+    bits: int
+    use_pwl: bool
+    segments: int
+
+
+def _node_value(key: _NodeKey, vals) -> jnp.ndarray:
+    node = Node(-1, key.op, (), key.shape, attrs=dict(key.attrs))
+    nvu_kw = dict(use_pwl=key.use_pwl, segments=key.segments)
+    op = key.op
+    if op == "matmul":
+        a, b, *bias = vals
+        return _matmul(node, a, b, bias[0] if bias else None,
+                       weight_resident=key.weight_resident,
+                       npe_quant=key.npe_quant, bits=key.bits,
+                       act_axis=key.act_axis)
+    if op == "softmax":
+        return _softmax(node, vals[0], pos=vals[1] if len(vals) > 1 else None,
+                        **nvu_kw)
+    if op == "layernorm":
+        x, gamma, *beta = vals
+        return _layernorm(node, x, gamma, beta[0] if beta else None,
+                          **nvu_kw)
+    if op == "rmsnorm":
+        return _rmsnorm(node, *vals, **nvu_kw)
+    if op == "act":
+        return nvu.activation(node.attrs["fn"], key.use_pwl,
+                              key.segments)(vals[0])
+    if op == "rope":
+        return _rope(node, *vals)
+    if op == "cache_append":
+        return _cache_append(node, *vals)
+    raise NotImplementedError(f"executor has no rule for {op!r}")
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _shared_node(key: _NodeKey, *vals) -> jnp.ndarray:
+    """A node's ops as a program of their own.  JAX traces it once per
+    key and input shapes and reuses that trace, and its lowering, for
+    every node that repeats it (a stack's layers, a layer's heads); XLA
+    inlines the calls, so the compiled graph program is unchanged."""
+    with jax.named_scope(key.cls):
+        return _node_value(key, vals)
+
+
+# ops whose nodes repeat a trace of `_shared_node`; the rest are a
+# primitive or two, a weight's own slice, or (MoE routing) share a
+# dispatch mask between nodes
+_SHARED_OPS = frozenset(("matmul", "softmax", "layernorm", "rmsnorm", "act",
+                         "rope", "cache_append"))
+
+
 def _nbytes(x) -> int:
     return int(x.size) * x.dtype.itemsize
 
@@ -266,21 +374,12 @@ def _use_counts(graph: Graph) -> Dict[int, int]:
     return uses
 
 
-def execute(program: Union[CompiledProgram, Graph], params: Any,
-            feeds: Dict[str, Any], *, cfg: Optional[ModelConfig] = None,
-            npe_quant: bool = False, bits: int = 8, use_pwl: bool = False,
-            segments: int = 16) -> ExecResult:
-    """Run the program on `feeds` (dict input-name -> array, optionally
-    batched) with `params` (the registry parameter tree).  NPE numerics
-    follow `cfg` when given (npe_quant / npe_quant_bits / npe_pwl /
-    npe_pwl_segments), else the explicit keyword flags."""
-    graph = program.graph if isinstance(program, CompiledProgram) else program
-    n_instrs = (len(program.instrs) if isinstance(program, CompiledProgram)
-                else sum(n.op not in FOLDED_OPS for n in graph.nodes))
-    if cfg is not None:
-        npe_quant, bits = cfg.npe_quant, cfg.npe_quant_bits
-        use_pwl, segments = cfg.npe_pwl, cfg.npe_pwl_segments
-
+def _interpret(graph: Graph, params: Any, feeds: Dict[str, Any], *,
+               npe_quant: bool, bits: int, use_pwl: bool, segments: int):
+    """Run `graph`'s nodes in order on `feeds`; returns (outputs,
+    cache_updates, kv_exports, peak live bytes).  Called while JAX
+    traces a graph's program, so the arrays are tracers and the live-byte
+    bookkeeping reads only their shapes and dtypes."""
     # batched-slot decode streams (vector `pos` input) quantize MMU
     # activations per ROW: each row of a merged (B, K) tile is a different
     # sequence's activation vector, so per-row scales keep the stream
@@ -292,7 +391,8 @@ def execute(program: Union[CompiledProgram, Graph], params: Any,
     env: Dict[int, jnp.ndarray] = {}
     live = 0
     peak = 0
-    mask_memo: Dict[Any, jnp.ndarray] = {}   # per-call dispatch-mask cache
+    mask_memo: Dict[Any, jnp.ndarray] = {}   # per-trace dispatch-mask cache
+    uses = _use_counts(graph)
 
     def put(nid: int, val):
         nonlocal live, peak
@@ -309,42 +409,24 @@ def execute(program: Union[CompiledProgram, Graph], params: Any,
             del env[nid]
         return val
 
-    def run(node: Node) -> None:
+    def run(node: Node, cls: str) -> None:
         op = node.op
-        if op == "input":
+        if op in _SHARED_OPS:
+            vals = [get(i) for i in node.inputs]
+            wres = (op == "matmul"
+                    and graph.node(node.inputs[1]).op == "param")
+            key = _NodeKey(op, cls, node.shape,
+                           tuple(sorted((k, v) for k, v in node.attrs.items()
+                                        if k != "name")),
+                           wres, act_axis, npe_quant, bits, use_pwl,
+                           segments)
+            put(node.id, _shared_node(key, *vals))
+        elif op == "param":
+            put(node.id, _slice_param(_param_leaf(params, node), node))
+        elif op == "input":
             x = jnp.asarray(feeds[node.attrs["name"]])
             put(node.id, x if node.dtype == "int32"
                 else x.astype(jnp.float32))
-        elif op == "param":
-            put(node.id, _resolve_param(params, node))
-        elif op == "matmul":
-            a, b = get(node.inputs[0]), get(node.inputs[1])
-            bias = get(node.inputs[2]) if len(node.inputs) > 2 else None
-            wres = graph.node(node.inputs[1]).op == "param"
-            put(node.id, _matmul(node, a, b, bias, weight_resident=wres,
-                                 npe_quant=npe_quant, bits=bits,
-                                 act_axis=act_axis))
-        elif op == "softmax":
-            x = get(node.inputs[0])
-            posv = (get(node.inputs[1]) if len(node.inputs) > 1 else None)
-            put(node.id, _softmax(node, x, pos=posv,
-                                  use_pwl=use_pwl, segments=segments))
-        elif op == "layernorm":
-            x, gamma = get(node.inputs[0]), get(node.inputs[1])
-            beta = get(node.inputs[2]) if len(node.inputs) > 2 else None
-            put(node.id, _layernorm(node, x, gamma, beta,
-                                    use_pwl=use_pwl, segments=segments))
-        elif op == "rmsnorm":
-            put(node.id, _rmsnorm(node, get(node.inputs[0]),
-                                  get(node.inputs[1]),
-                                  use_pwl=use_pwl, segments=segments))
-        elif op == "act":
-            fn = nvu.activation(node.attrs["fn"], use_pwl, segments)
-            put(node.id, fn(get(node.inputs[0])))
-        elif op == "rope":
-            x = get(node.inputs[0])
-            posv = (get(node.inputs[1]) if len(node.inputs) > 1 else None)
-            put(node.id, _rope(node, x, posv))
         elif op == "add":
             put(node.id, get(node.inputs[0]) + get(node.inputs[1]))
         elif op == "mul":
@@ -383,37 +465,6 @@ def execute(program: Union[CompiledProgram, Graph], params: Any,
                                              get(node.inputs[2]),
                                              memo=mask_memo,
                                              key=node.inputs[1]))
-        elif op == "cache_append":
-            c = get(node.inputs[0])
-            new = get(node.inputs[1])
-            posv = get(node.inputs[2])
-            slot = node.attrs.get("slot")
-            if slot is not None:
-                # batched stream: row `slot` of the merged (B, hd)
-                # projection, written at this slot's own position
-                new = new[..., slot:slot + 1, :]
-                posv = posv[..., slot]
-            if node.attrs.get("rows"):
-                # chunked-prefill burst: write all C rows of `new` at their
-                # absolute positions posv[r].  The one-hot einsum copies
-                # each row exactly (1.0 * x plus zeros), so a chunked bank
-                # is bitwise-equal to the monolithic prefill's rows.
-                cap = node.shape[-2]
-                idx = posv.astype(jnp.int32)
-                onehot = (jnp.arange(cap, dtype=jnp.int32)[:, None]
-                          == idx[None, :])
-                write = jnp.einsum("cr,...rd->...cd",
-                                   onehot.astype(new.dtype), new)
-                keep = ~onehot.any(axis=1)
-                put(node.id, jnp.where(keep[:, None], c, write))
-            else:
-                cap = node.shape[-2]
-                if node.attrs.get("window"):
-                    # ring bank: the write wraps — the bank holds the last
-                    # `cap` tokens while the position counter keeps growing
-                    posv = posv % cap
-                hit = (jnp.arange(cap, dtype=jnp.int32) == posv)[:, None]
-                put(node.id, jnp.where(hit, new, c))
         elif op == "slot_select":
             x = get(node.inputs[0])
             i = node.attrs["index"]
@@ -424,17 +475,111 @@ def execute(program: Union[CompiledProgram, Graph], params: Any,
         else:
             raise NotImplementedError(f"executor has no rule for {op!r}")
 
-    with span(EXEC_EXECUTE, nodes=len(graph.nodes)):
-        uses = _use_counts(graph)
-        for node in graph.nodes:
-            with span(node_span(graph, node)):
-                run(node)
+    for node in graph.nodes:
+        cls = node_class(graph, node)
+        with jax.named_scope(cls):
+            run(node, cls)
 
-    return ExecResult([env[o] for o in graph.outputs], peak, n_instrs,
-                      {name: env[nid]
-                       for name, nid in graph.cache_updates.items()},
-                      {name: env[nid]
-                       for name, nid in graph.kv_exports.items()})
+    return ([env[o] for o in graph.outputs],
+            {name: env[nid] for name, nid in graph.cache_updates.items()},
+            {name: env[nid] for name, nid in graph.kv_exports.items()},
+            peak)
+
+
+def _signature(feeds: Dict[str, Any]) -> tuple:
+    """Names, shapes and dtypes of `feeds`, the same for the arrays of a
+    call and the tracers JAX traces them as."""
+    return tuple((name, tuple(v.shape),
+                  jax.dtypes.canonicalize_dtype(v.dtype).name)
+                 for name, v in sorted(feeds.items()))
+
+
+@dataclass
+class _Executable:
+    """One graph's jitted program under fixed numerics.  `peaks` holds
+    the peak live bytes each traced feed signature gave; `marks` the
+    node marks of one execution (`_mark`)."""
+    fn: Callable
+    feed_names: Tuple[str, ...]
+    peaks: Dict[tuple, int]
+    marks: Tuple[Tuple[str, bool], ...]
+
+
+def _marks(graph: Graph, npe_quant: bool) -> Tuple[Tuple[str, bool], ...]:
+    """(span name, quantizes its weight) of each node, in graph order."""
+    out = []
+    for node in graph.nodes:
+        cls = node_class(graph, node)
+        out.append((EXEC_PREFIX + cls, cls == "mmu" and npe_quant
+                    and node.attrs.get("quantize", True)))
+    return tuple(out)
+
+
+def _mark(marks: Tuple[Tuple[str, bool], ...]) -> None:
+    """One empty span per node of an execution, for a profile to count
+    by class; a weight's quantization is marked inside its matmul."""
+    for name, quantizes in marks:
+        with span(name):
+            if quantizes:
+                with span(EXEC_QUANTIZE_WEIGHT):
+                    pass
+
+
+def executable(graph: Graph, *, npe_quant: bool, bits: int,
+               use_pwl: bool, segments: int) -> _Executable:
+    """`graph`'s jitted program for these numerics, made on first use
+    and memoized on the graph: every engine that shares a compiled
+    program shares its executable.  `fn(params, feeds)` returns
+    (outputs, cache_updates, kv_exports); weights and feeds are
+    arguments, the graph and the numerics are fixed."""
+    key = (npe_quant, bits, use_pwl, segments)
+    exe = graph.executables.get(key)
+    if exe is None:
+        peaks: Dict[tuple, int] = {}
+
+        def program(params, feeds):
+            with span(EXEC_TRACE, nodes=len(graph.nodes)):
+                *res, peak = _interpret(graph, params, feeds,
+                                        npe_quant=npe_quant, bits=bits,
+                                        use_pwl=use_pwl, segments=segments)
+            peaks[_signature(feeds)] = peak
+            return tuple(res)
+
+        names = tuple(dict.fromkeys(n.attrs["name"] for n in graph.nodes
+                                    if n.op in ("input", "cache")))
+        exe = graph.executables[key] = _Executable(
+            jax.jit(program), names, peaks, _marks(graph, npe_quant))
+    return exe
+
+
+def _as_arg(v):
+    return v if isinstance(v, jax.Array) else np.asarray(v)
+
+
+def execute(program: Union[CompiledProgram, Graph], params: Any,
+            feeds: Dict[str, Any], *, cfg: Optional[ModelConfig] = None,
+            npe_quant: bool = False, bits: int = 8, use_pwl: bool = False,
+            segments: int = 16) -> ExecResult:
+    """Run the program on `feeds` (dict input-name -> array, optionally
+    batched) with `params` (the registry parameter tree), as the graph's
+    one jitted program (`executable`).  NPE numerics follow `cfg` when
+    given (npe_quant / npe_quant_bits / npe_pwl / npe_pwl_segments),
+    else the explicit keyword flags."""
+    graph = program.graph if isinstance(program, CompiledProgram) else program
+    n_instrs = (len(program.instrs) if isinstance(program, CompiledProgram)
+                else sum(n.op not in FOLDED_OPS for n in graph.nodes))
+    if cfg is not None:
+        npe_quant, bits = cfg.npe_quant, cfg.npe_quant_bits
+        use_pwl, segments = cfg.npe_pwl, cfg.npe_pwl_segments
+    exe = executable(graph, npe_quant=npe_quant, bits=bits, use_pwl=use_pwl,
+                     segments=segments)
+    args = {name: _as_arg(feeds[name]) for name in exe.feed_names}
+    with span(EXEC_EXECUTE, nodes=len(graph.nodes)):
+        outputs, updates, exports = exe.fn(params, args)
+        if span.is_enabled():       # only a recording profiler keeps them
+            _mark(exe.marks)
+    return ExecResult(outputs, exe.peaks[_signature(args)], n_instrs,
+                      updates, exports)
 
 
 class DecodeSession:

@@ -138,6 +138,8 @@ class Graph:
         # serving-prefill graphs: canonical cache name ("enc0.kv0.k") ->
         # the (S, head_dim) node whose rows seed a decode cache bank
         self.kv_exports: Dict[str, int] = {}
+        # numerics -> this graph's jitted program (repro.npec.exec)
+        self.executables: Dict[tuple, Any] = {}
 
     # --- construction ----------------------------------------------------
 

@@ -3,13 +3,24 @@
 Every layer boundary of the numeric serving path opens a
 `jax.profiler.TraceAnnotation` under one of the names below: the
 engine's admission and decode, each host read of a device result, the
-`DecodeSession` slot lifecycle, `exec.execute`, every graph node's
-dispatch (named by its op class) and the MMU weight quantization.  They
-land in the profiler's own trace beside the device's ops, so an idle
-stretch of the device names the program phase the host was in.  With no
-profiler running a span costs about a microsecond, and nothing turns
-them off.  Spans nest on the host thread: a span's parent is the span
-that encloses it.
+`DecodeSession` slot lifecycle and each `exec.execute`.  They land in
+the profiler's own trace beside the device's ops, so an idle stretch of
+the device names the program phase the host was in.  With no profiler
+running a span costs about a microsecond, and nothing turns them off.
+Spans nest on the host thread: a span's parent is the span that
+encloses it.
+
+The executor runs each graph as one jitted program, so a node has no
+host work of its own to time.  Each execution instead marks its nodes:
+while a profiler records, `exec.execute` opens one empty
+`npec.exec.<class>` span per graph node, in graph order, with an
+`npec.exec.quantize_weight` inside each matmul that quantizes its
+weight, right after it dispatches the program.  Their counts are the
+nodes each execution ran; their length is the host's per-node cost,
+which is nothing.  `npec.exec.trace` opens around each trace of a
+program by JAX, so its count in a window is the number of executor
+retraces.  In the compiled program each node's device ops carry the
+node's class as a `jax.named_scope`.
 
 Unlike `Tracer` (tracer.py), which stamps modelled overlay cycles and is
 byte-identical by design, these spans measure host wall time and are
@@ -20,9 +31,6 @@ from __future__ import annotations
 
 from jax.profiler import TraceAnnotation as span
 
-# opened in repro.core.quant.dense_maybe_quant, which imports nothing of npec
-from repro.core.quant import QUANTIZE_WEIGHT_SPAN as EXEC_QUANTIZE_WEIGHT
-
 ENGINE_ADMIT = "npec.engine.admit"          # rid, rows
 ENGINE_DECODE = "npec.engine.decode"        # active, bucket
 ENGINE_SYNC = "npec.engine.sync"            # the host waits on the device
@@ -30,10 +38,13 @@ SESSION_LOAD_SLOT = "npec.session.load_slot"    # slot, rows
 SESSION_RESET_SLOT = "npec.session.reset_slot"  # slot
 SESSION_MIGRATE = "npec.session.migrate"        # capacity
 EXEC_EXECUTE = "npec.exec.execute"          # nodes
+EXEC_TRACE = "npec.exec.trace"              # nodes; opens only as JAX traces
 EXEC_PREFIX = "npec.exec."
+EXEC_QUANTIZE_WEIGHT = "npec.exec.quantize_weight"  # inside an `mmu` mark
 
-# IR op -> the class its dispatch span is named by (`npec.exec.<class>`).
-# A matmul is `mmu` when its second operand is a parameter (a resident
+# IR op -> the class its node mark is named by (`npec.exec.<class>`), and
+# the `jax.named_scope` its device ops carry in the compiled program.  A
+# matmul is `mmu` when its second operand is a parameter (a resident
 # weight) and `attention` when both operands are activations.
 OP_CLASS = {
     "input": "feed",
@@ -46,12 +57,10 @@ OP_CLASS = {
     "add": "tensor", "mul": "tensor", "concat": "tensor",
     "reshape": "tensor", "embed": "tensor",
 }
-_NODE_SPAN = {op: EXEC_PREFIX + cls for op, cls in OP_CLASS.items()}
-_ATTENTION_SPAN = EXEC_PREFIX + "attention"
 
 
-def node_span(graph, node) -> str:
-    """The span name of `node`'s dispatch in `graph`."""
+def node_class(graph, node) -> str:
+    """The class of `node` in `graph`: its mark is `EXEC_PREFIX` + this."""
     if node.op == "matmul" and graph.node(node.inputs[1]).op != "param":
-        return _ATTENTION_SPAN
-    return _NODE_SPAN[node.op]
+        return "attention"
+    return OP_CLASS[node.op]
