@@ -3,9 +3,16 @@
 The MMU consumes int8 or int16 fixed-point operands and always emits int16
 activations for the NVU ("the output of the MMU is written out ... as 16-bit
 fixed point values").  We implement symmetric linear quantization with
-per-channel (per-output-feature) weight scales and per-tensor activation
-scales, plus the quantized-dense building block used by the model zoo when
-`npe_quant` is on.
+per-column (per-output-feature) weight scales and per-row (per-token)
+activation scales, plus the quantized-dense building block used by the
+model zoo and the npec executor when `npe_quant` is on.
+
+Per-row activation scales make a token's MMU result depend on that
+token's own activations alone: whole prefill, chunked prefill and
+(batched) decode give it the same numerics, and a causal prefill stays
+causal.  A per-tensor scale would tie every row to the largest
+activation of the whole tile, rows after it included, and coarsen the
+int8 step as prompts grow.
 
 lax.dot_general with int8 operands and preferred_element_type=int32 lowers
 onto the MXU's native int8 path on TPU (2x the bf16 rate — the analogue of
@@ -70,19 +77,16 @@ def int_matmul(aq: jnp.ndarray, bq: jnp.ndarray) -> jnp.ndarray:
 
 
 def quant_dense(x: jnp.ndarray, w: QTensor, bias: Optional[jnp.ndarray] = None,
-                act_bits: int = 8,
-                act_axis: Optional[int] = None) -> jnp.ndarray:
-    """The MMU primitive: quantize activations, integer matmul, dequantize.
+                act_bits: int = 8) -> jnp.ndarray:
+    """The MMU primitive on (rows, K) activations: quantize each row with
+    its own scale, integer matmul, dequantize.
 
-    Weight scales are per-output-channel (shape (1, N) after keepdims), so
-    dequantization is a single row-broadcast multiply in the epilogue —
-    exactly the MMU's "accumulate then quantize" stage.  `act_axis=0`
-    scales activations per ROW instead of per tensor — the batched decode
-    streams' semantic, where each row of a merged (B, K) tile is a
-    different sequence's activation vector arriving separately.
+    Row scales are (rows, 1) and weight scales per output column (1, N),
+    so dequantization is one outer-product multiply in the epilogue —
+    exactly the MMU's "accumulate then quantize" stage.
     """
     dt = x.dtype
-    xa = quantize(x, act_bits, axis=act_axis)
+    xa = quantize(x, act_bits, axis=0)
     acc = int_matmul(xa.q, w.q)                        # int32
     out = acc.astype(jnp.float32) * (xa.scale * w.scale.reshape(1, -1))
     if bias is not None:
@@ -93,16 +97,14 @@ def quant_dense(x: jnp.ndarray, w: QTensor, bias: Optional[jnp.ndarray] = None,
 
 def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray,
                       bias: Optional[jnp.ndarray] = None,
-                      npe_quant: bool = False, bits: int = 8,
-                      act_axis: Optional[int] = None) -> jnp.ndarray:
+                      npe_quant: bool = False, bits: int = 8) -> jnp.ndarray:
     """Dense layer that routes through the MMU when the NPE mode is on.
 
     `w` is kept in float master form (training still works); quantization is
     applied functionally, matching the paper's post-training quantization
-    flow ([28] Q8BERT-style symmetric).  `act_axis=0` quantizes activation
-    rows independently (after flattening lead axes): bitwise-identical to
-    per-tensor for a single row, and what keeps a merged batched-decode
-    tile equivalent to its B independent per-sequence rows.
+    flow ([28] Q8BERT-style symmetric).  Lead axes are flattened to rows
+    and each row is quantized on its own, so the result of a stacked
+    (R, K) input equals that of R one-row calls, bitwise.
     """
     if not npe_quant:
         return x @ w if bias is None else x @ w + bias
@@ -111,14 +113,14 @@ def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray,
     if bits == 8:
         # True integer path: int8 x int8 -> int32 is exact for K <= 2^17.
         wq = quantize(w, bits, axis=1)
-        y = quant_dense(x2, wq, bias, act_bits=bits, act_axis=act_axis)
+        y = quant_dense(x2, wq, bias, act_bits=bits)
     else:
         # 16-bit MMU mode.  int16 products overflow int32 accumulators and
         # the TPU MXU has no int16 mode, so the 16-bit variant is modeled as
         # fake-quantization to the int16 grid with f32 accumulation — the
         # quantization error (the quantity under study) is identical; only
         # accumulator rounding differs (f32 vs the FPGA's wide adders).
-        xq = fake_quantize(x2.astype(jnp.float32), bits, axis=act_axis)
+        xq = fake_quantize(x2.astype(jnp.float32), bits, axis=0)
         wq = fake_quantize(w.astype(jnp.float32), bits, axis=1)
         y = xq @ wq
         if bias is not None:

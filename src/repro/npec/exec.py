@@ -9,7 +9,9 @@ against the corresponding jnp model's outputs (tests/test_npec.py, and
 
 Semantics mirror the jnp modules op-for-op:
   * weight matmuls   -> `quant.dense_maybe_quant` (int8/int16 MMU when
-                        npe_quant) + bias epilogue;
+                        npe_quant, one activation scale per row, so every
+                        stream kind quantizes a token alike) + bias
+                        epilogue;
   * QK^T / AV        -> f32-accumulated einsums on the activation path
                         (never quantized, matching `common.attention_scores`);
   * softmax / norms / activations -> `nvu.softmax` / layernorm / rmsnorm /
@@ -115,7 +117,7 @@ def _slice_param(v, node: Node) -> jnp.ndarray:
 
 
 def _matmul(node: Node, a, b, bias, *, weight_resident: bool,
-            npe_quant: bool, bits: int, act_axis=None):
+            npe_quant: bool, bits: int):
     if weight_resident and not node.attrs.get("quantize", True):
         # float-pinned weight matmul (MoE router / expert streams):
         # `models/moe.apply` computes these as plain activation-dtype
@@ -126,8 +128,7 @@ def _matmul(node: Node, a, b, bias, *, weight_resident: bool,
         # (the tied-embedding logits head) is stored transposed, exactly as
         # models/common.logits_out feeds embed.T to the quantized dense
         w = jnp.swapaxes(b, -1, -2) if node.attrs.get("transpose_b") else b
-        y = dense_maybe_quant(a, w, None, npe_quant=npe_quant, bits=bits,
-                              act_axis=act_axis)
+        y = dense_maybe_quant(a, w, None, npe_quant=npe_quant, bits=bits)
     elif node.attrs.get("transpose_b"):
         y = jnp.einsum("...ik,...jk->...ij", a, b,
                        preferred_element_type=jnp.float32)
@@ -302,7 +303,6 @@ class _NodeKey(NamedTuple):
     shape: Tuple[int, ...]
     attrs: Tuple[Tuple[str, Any], ...]   # sorted; a bank's name left out
     weight_resident: bool
-    act_axis: Optional[int]
     npe_quant: bool
     bits: int
     use_pwl: bool
@@ -317,8 +317,7 @@ def _node_value(key: _NodeKey, vals) -> jnp.ndarray:
         a, b, *bias = vals
         return _matmul(node, a, b, bias[0] if bias else None,
                        weight_resident=key.weight_resident,
-                       npe_quant=key.npe_quant, bits=key.bits,
-                       act_axis=key.act_axis)
+                       npe_quant=key.npe_quant, bits=key.bits)
     if op == "softmax":
         return _softmax(node, vals[0], pos=vals[1] if len(vals) > 1 else None,
                         **nvu_kw)
@@ -380,14 +379,6 @@ def _interpret(graph: Graph, params: Any, feeds: Dict[str, Any], *,
     cache_updates, kv_exports, peak live bytes).  Called while JAX
     traces a graph's program, so the arrays are tracers and the live-byte
     bookkeeping reads only their shapes and dtypes."""
-    # batched-slot decode streams (vector `pos` input) quantize MMU
-    # activations per ROW: each row of a merged (B, K) tile is a different
-    # sequence's activation vector, so per-row scales keep the stream
-    # bitwise-equivalent to B independent per-sequence rollouts
-    pos_nid = graph.inputs.get("pos")
-    act_axis = (0 if pos_nid is not None and graph.node(pos_nid).shape
-                else None)
-
     env: Dict[int, jnp.ndarray] = {}
     live = 0
     peak = 0
@@ -418,8 +409,7 @@ def _interpret(graph: Graph, params: Any, feeds: Dict[str, Any], *,
             key = _NodeKey(op, cls, node.shape,
                            tuple(sorted((k, v) for k, v in node.attrs.items()
                                         if k != "name")),
-                           wres, act_axis, npe_quant, bits, use_pwl,
-                           segments)
+                           wres, npe_quant, bits, use_pwl, segments)
             put(node.id, _shared_node(key, *vals))
         elif op == "param":
             put(node.id, _slice_param(_param_leaf(params, node), node))

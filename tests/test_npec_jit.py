@@ -1,6 +1,6 @@
 """The executor runs each compiled graph as one jitted XLA program.
 
-Five gates:
+Six gates:
   * one trace per graph — a decode session's steps at new positions and
     tokens reuse the program traced on the first step, and a second
     engine sharing the stream cache traces nothing;
@@ -9,6 +9,8 @@ Five gates:
     what the op-by-op interpreter reported for the same graphs;
   * numerics — the jitted program's outputs match the same node loop
     run op by op (`jax.disable_jit`), float and NPE mode;
+  * causality — an NPE-mode prefill row's logits do not see the prompt
+    rows after it;
   * node marks — one per node, and a weight quantization marked inside
     each matmul that quantizes its weight (bench/tests holds the marks'
     tree in a profile of a served run).
@@ -180,6 +182,25 @@ def test_jitted_outputs_match_the_op_by_op_interpreter(arch, mode, tol_for):
         want = _run(arch, mode)
     err = float(np.max(np.abs(got - want)))
     assert err <= tol_for(mode) * max(1.0, float(np.max(np.abs(want)))), err
+
+
+@pytest.mark.parametrize("arch", ["bert_base", "glm4_9b"])
+def test_npe_prefill_is_causal(arch):
+    """Replacing an int8 NPE-mode prefill's prompt from row i on leaves
+    the logits of rows 0..i-1 as they were: each MMU row is quantized
+    with its own scale, and the masked softmax gives later keys weight
+    exactly 0."""
+    cfg, params = _setup(arch)
+    S, i = 12, 5
+    prog = npec.compile_prefill(cfg, S, HW, bits=8)
+    ncfg = cfg.with_npe(quant_bits=8, segments=16)
+    a = np.asarray(_tokens(cfg, (S,)), np.int32)
+    b = a.copy()
+    b[i:] = (a[i:] + 1 + np.arange(S - i)) % cfg.vocab_size
+    la, lb = (np.asarray(npec.execute(prog, params, {"tokens": t},
+                                      cfg=ncfg)[0]) for t in (a, b))
+    assert float(np.max(np.abs(la[:i] - lb[:i]))) <= 1e-6
+    assert float(np.max(np.abs(la[i:] - lb[i:]))) > 0.0
 
 
 @pytest.mark.parametrize("npe", [False, True])

@@ -86,9 +86,9 @@ def test_batched_stream_matches_sequential_float(B, float_tol):
 
 
 def test_batched_stream_matches_sequential_npe_mode(npe_tol):
-    """Same in NPE mode (int8 MMU + PWL NVU both sides): per-ROW
-    activation scales (`core.quant` act_axis=0) keep each merged-tile row
-    quantized exactly as its 1-row per-sequence counterpart, so batched
+    """Same in NPE mode (int8 MMU + PWL NVU both sides): `core.quant`
+    scales each activation row on its own, so each merged-tile row is
+    quantized exactly as its 1-row per-sequence counterpart and batched
     streams stay faithful; gated at the shared NPE tolerance."""
     assert _batched_vs_sequential_err("bert_base", 4, steps=4, npe=True,
                                       bits=8) < npe_tol

@@ -50,6 +50,12 @@ def _params(cfg):
 def _chunked_banks(cfg, params, prompt, chunk, capacity, npe_cfg=None):
     """Run the prompt as causal cache slices (the engine's chunked-admit
     path, standalone) and return the final {name: (S, hd)} banks."""
+    return _chunked_prefill(cfg, params, prompt, chunk, capacity,
+                            npe_cfg)[0]
+
+
+def _chunked_prefill(cfg, params, prompt, chunk, capacity, npe_cfg=None):
+    """`_chunked_banks`, and the last prompt row's logits."""
     import jax
 
     caches = None
@@ -68,10 +74,16 @@ def _chunked_banks(cfg, params, prompt, chunk, capacity, npe_cfg=None):
             caches.update({k: np.asarray(v)
                            for k, v in res.cache_updates.items()})
     S = len(prompt)
-    return {name: arr[:S] for name, arr in caches.items()}
+    return ({name: arr[:S] for name, arr in caches.items()},
+            np.asarray(res[0])[-1])
 
 
 def _whole_banks(cfg, params, prompt, npe_cfg=None):
+    return _whole_prefill(cfg, params, prompt, npe_cfg)[0]
+
+
+def _whole_prefill(cfg, params, prompt, npe_cfg=None):
+    """`_whole_banks`, and the last prompt row's logits."""
     import jax
 
     prog = npec.compile_prefill(cfg, len(prompt), HW, bits=16)
@@ -79,7 +91,8 @@ def _whole_banks(cfg, params, prompt, npe_cfg=None):
         res = npec.execute(prog, params,
                            {"tokens": np.asarray(prompt, np.int32)},
                            cfg=npe_cfg)
-    return {k: np.asarray(v) for k, v in res.kv_exports.items()}
+    return ({k: np.asarray(v) for k, v in res.kv_exports.items()},
+            np.asarray(res[0])[-1])
 
 
 def _assert_banks_match(got, want, tol):
@@ -124,6 +137,25 @@ def test_chunked_prefill_cache_bank_npe_mode():
     got = _chunked_banks(cfg, params, prompt, 4, capacity=12,
                          npe_cfg=npe_cfg)
     _assert_banks_match(got, want, NPE_TOL)
+
+
+def test_chunked_prefill_last_logits_npe_mode():
+    """Int8 NPE mode, glm4: whole and chunked prefill give the last
+    prompt row the same logits to the conformance suite's 5e-3, since
+    every MMU row is quantized on its own whatever tile it arrives in."""
+    from conftest import NPE_TOL
+
+    cfg = _smoke_cfg("glm4_9b")
+    params = _params(cfg)
+    npe_cfg = cfg.with_npe(quant_bits=8)
+    rng = np.random.default_rng(8)
+    prompt = rng.integers(0, cfg.vocab_size, size=13).astype(np.int32)
+    want_banks, want = _whole_prefill(cfg, params, prompt, npe_cfg=npe_cfg)
+    got_banks, got = _chunked_prefill(cfg, params, prompt, 4, capacity=16,
+                                      npe_cfg=npe_cfg)
+    _assert_banks_match(got_banks, want_banks, NPE_TOL)
+    err = float(np.abs(got - want).max())
+    assert err <= NPE_TOL, err
 
 
 def _engine_tokens(cfg, params, prompts, chunk, capacity=16, gen=4):

@@ -88,3 +88,21 @@ def test_fixedpoint_mul_add():
     a, b = jnp.float32(1.5), jnp.float32(2.25)
     assert float(fp.fixed_mul(a, b, fp.Q16_8)) == 3.375
     assert float(fp.fixed_add(a, b, fp.Q16_8)) == 3.75
+
+
+@pytest.mark.parametrize("bits", [8, 16])
+def test_stacked_rows_quantize_as_single_rows(bits):
+    """Each activation row gets its own scale: a stacked (R, K) input,
+    rows of very different magnitudes, gives bitwise what R one-row calls
+    give — so a token's MMU result never depends on the other rows of its
+    tile (prefill rows after it, or other slots of a batched step)."""
+    R, K, N = 6, 64, 24
+    mag = jnp.logspace(-2, 2, R)[:, None]
+    x = mag * jax.random.normal(KEY, (R, K))
+    w = jax.random.normal(jax.random.PRNGKey(1), (K, N)) / np.sqrt(K)
+    b = jax.random.normal(jax.random.PRNGKey(2), (N,))
+    got = quant.dense_maybe_quant(x, w, b, npe_quant=True, bits=bits)
+    want = jnp.concatenate([
+        quant.dense_maybe_quant(x[r:r + 1], w, b, npe_quant=True, bits=bits)
+        for r in range(R)])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -88,7 +88,7 @@ def test_int8_dense_compiles_to_int8_dot(one_chip):
     """The NPE-mode MMU matmul of a batched decode step: int8 operands,
     int32 accumulation."""
     c = _compile(lambda x, w: dense_maybe_quant(x, w, npe_quant=True,
-                                                bits=8, act_axis=0),
+                                                bits=8),
                  one_chip, (4, 768), (768, 3072))
     hlo = c.as_text()
     assert "s8[" in hlo and "s32[" in hlo
