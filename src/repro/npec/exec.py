@@ -572,6 +572,29 @@ def execute(program: Union[CompiledProgram, Graph], params: Any,
                       updates, exports)
 
 
+def _slot_banks(banks: Tuple[jnp.ndarray, ...],
+                rows: Tuple[Any, ...]) -> Tuple[jnp.ndarray, ...]:
+    """A slot's banks after a load or a reset: bank i holds `rows[i]`
+    (cast to float32, lead axes dropped) at rows [0, S) and zeros after
+    them; the banks past `rows` hold zeros only."""
+    with span(EXEC_TRACE, rows=rows[0].shape[-2] if rows else 0,
+              banks=len(banks)):
+        out = []
+        for i, bank in enumerate(banks):
+            new = jnp.zeros_like(bank)
+            if i < len(rows):
+                r = rows[i].astype(jnp.float32)
+                new = new.at[: r.shape[-2]].set(r.reshape(r.shape[-2:]))
+            out.append(new)
+    return tuple(out)
+
+
+# One dispatch writes a whole slot.  The banks are donated and kept as
+# arguments although only their shapes are read: unused, jit would drop
+# them and each write would allocate the slot anew.
+_write_slot = jax.jit(_slot_banks, donate_argnums=0, keep_unused=True)
+
+
 class DecodeSession:
     """Stateful execution of a compiled decode stream.
 
@@ -592,7 +615,9 @@ class DecodeSession:
         projections.  Slots advance independently: `step(tokens, active=)`
         bumps only active slots, `reset_slot` recycles one for a new
         request, and `load_slot` seeds its banks from an executed prefill
-        (`trace_prefill` kv exports).  This is the stream the serving
+        (`trace_prefill` kv exports).  Each writes all of the slot's banks
+        with one dispatch of a jitted program that donates them, so a
+        write reuses the slot's buffers.  This is the stream the serving
         engine (repro.npec.runtime) clocks.
 
     `params` is the registry parameter tree; NPE numerics follow `cfg`
@@ -696,39 +721,48 @@ class DecodeSession:
         if not 0 <= slot < self.slots:
             raise ValueError(f"slot {slot} out of range [0, {self.slots})")
 
+    def _slot_bank_names(self, slot: int) -> List[str]:
+        key = f".slot{slot}."
+        return [name for name in self.caches if key in name]
+
+    def _write_banks(self, names: List[str], rows: List[Any]) -> None:
+        new = _write_slot(tuple(self.caches[n] for n in names),
+                          tuple(_as_arg(r) for r in rows))
+        self.caches.update(zip(names, new))
+
     def reset_slot(self, slot: int) -> None:
         """Recycle one slot: zero its cache banks and position counter."""
         self._check_slot(slot)
-        key = f".slot{slot}."
-        with span(SESSION_RESET_SLOT, slot=slot):
-            for name in self.caches:
-                if key in name:
-                    self.caches[name] = jnp.zeros_like(self.caches[name])
+        names = self._slot_bank_names(slot)
+        with span(SESSION_RESET_SLOT, slot=slot, banks=len(names)):
+            self._write_banks(names, [])
         self.pos[slot] = 0
 
     def load_slot(self, slot: int, kv: Dict[str, jnp.ndarray],
                   n_tokens: int) -> None:
         """Seed one slot from an executed serving prefill: `kv` maps the
         canonical cache names (`ExecResult.kv_exports`) to (S, head_dim)
-        rows, written into this slot's banks at positions [0, S); the
-        slot's counter starts at `n_tokens`."""
+        rows, written into this slot's banks at positions [0, S), every
+        other row of the slot zeroed; the slot's counter starts at
+        `n_tokens`."""
         self._check_slot(slot)
         if n_tokens > self.capacity:
             raise ValueError(
                 f"prefill of {n_tokens} tokens exceeds the compiled cache "
                 f"capacity {self.capacity}")
-        with span(SESSION_LOAD_SLOT, slot=slot, rows=n_tokens):
-            self.reset_slot(slot)
-            for name, rows in kv.items():
-                base, leaf = name.rsplit(".", 1)
-                bank = f"{base}.slot{slot}.{leaf}"
-                if bank not in self.caches:
-                    raise KeyError(
-                        f"no cache bank {bank!r} for export {name!r}")
-                arr = jnp.asarray(rows, jnp.float32)
-                arr = arr.reshape(arr.shape[-2:])   # drop any lead axes
-                self.caches[bank] = (
-                    self.caches[bank].at[: arr.shape[0]].set(arr))
+        loaded: Dict[str, Any] = {}
+        for name, rows in kv.items():
+            base, leaf = name.rsplit(".", 1)
+            bank = f"{base}.slot{slot}.{leaf}"
+            if bank not in self.caches:
+                raise KeyError(
+                    f"no cache bank {bank!r} for export {name!r}")
+            loaded[bank] = rows
+        names = list(loaded) + [n for n in self._slot_bank_names(slot)
+                                if n not in loaded]
+        with span(SESSION_LOAD_SLOT, slot=slot, rows=n_tokens,
+                  banks=len(names)):
+            self._write_banks(names, list(loaded.values()))
         self.pos[slot] = n_tokens
 
     # --- bucket migration (length-bucketed serving) ------------------------

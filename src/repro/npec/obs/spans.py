@@ -18,8 +18,8 @@ while a profiler records, `exec.execute` opens one empty
 weight, right after it dispatches the program.  Their counts are the
 nodes each execution ran; their length is the host's per-node cost,
 which is nothing.  `npec.exec.trace` opens around each trace of a
-program by JAX, so its count in a window is the number of executor
-retraces.  In the compiled program each node's device ops carry the
+program by JAX, a graph's or the session's slot writer's, so its count
+in a window is the number of executor retraces.  In the compiled program each node's device ops carry the
 node's class as a `jax.named_scope`.
 
 Unlike `Tracer` (tracer.py), which stamps modelled overlay cycles and is
@@ -34,11 +34,12 @@ from jax.profiler import TraceAnnotation as span
 ENGINE_ADMIT = "npec.engine.admit"          # rid, rows
 ENGINE_DECODE = "npec.engine.decode"        # active, bucket
 ENGINE_SYNC = "npec.engine.sync"            # the host waits on the device
-SESSION_LOAD_SLOT = "npec.session.load_slot"    # slot, rows
-SESSION_RESET_SLOT = "npec.session.reset_slot"  # slot
+SESSION_LOAD_SLOT = "npec.session.load_slot"    # slot, rows, banks
+SESSION_RESET_SLOT = "npec.session.reset_slot"  # slot, banks
 SESSION_MIGRATE = "npec.session.migrate"        # capacity
 EXEC_EXECUTE = "npec.exec.execute"          # nodes
-EXEC_TRACE = "npec.exec.trace"              # nodes; opens only as JAX traces
+EXEC_TRACE = "npec.exec.trace"              # nodes, or a slot write's rows and
+                                            # banks; opens only as JAX traces
 EXEC_PREFIX = "npec.exec."
 EXEC_QUANTIZE_WEIGHT = "npec.exec.quantize_weight"  # inside an `mmu` mark
 

@@ -1,6 +1,6 @@
 """The executor runs each compiled graph as one jitted XLA program.
 
-Six gates:
+Seven gates:
   * one trace per graph — a decode session's steps at new positions and
     tokens reuse the program traced on the first step, and a second
     engine sharing the stream cache traces nothing;
@@ -13,7 +13,10 @@ Six gates:
     rows after it;
   * node marks — one per node, and a weight quantization marked inside
     each matmul that quantizes its weight (bench/tests holds the marks'
-    tree in a profile of a served run).
+    tree in a profile of a served run);
+  * slot writes — a session's `load_slot` and `reset_slot` leave the
+    banks bitwise as the eager per-bank writes did, each in one traced
+    program that donates the slot's banks.
 """
 import dataclasses
 import re
@@ -216,3 +219,112 @@ def test_node_marks_follow_the_graph(npe):
     pinned = [n for n in mmu if not n.attrs.get("quantize", True)]
     assert pinned                       # the router and expert streams
     assert sum(q for _, q in marks) == (len(mmu) - len(pinned)) * npe
+
+
+def _slot_session(arch, capacity, slots=3):
+    """An NPE-mode batched session whose banks all hold decoded rows."""
+    cfg, params = _setup(arch)
+    ncfg = cfg.with_npe(quant_bits=8, segments=16)
+    prog = npec.compile_decode(cfg, capacity, HW, bits=8, batch=slots)
+    sess = npec.DecodeSession(prog, params, cfg=ncfg)
+    for t in range(2):
+        sess.step(np.arange(3 + t, 3 + t + slots, dtype=np.int32))
+    return cfg, params, ncfg, sess
+
+
+def _exports(cfg, params, ncfg, S):
+    prog = npec.compile_prefill(cfg, S, HW, bits=8)
+    toks = np.asarray(_tokens(cfg, (S,)), np.int32)
+    return npec.execute(prog, params, {"tokens": toks}, cfg=ncfg).kv_exports
+
+
+def _eager_load(banks, slot, kv):
+    """The slot's banks as the eager per-bank writes left them: each
+    zeroed, then each exported bank's rows [0, S) set."""
+    import jax.numpy as jnp
+
+    key = f".slot{slot}."
+    out = {n: jnp.zeros_like(b) if key in n else b for n, b in banks.items()}
+    for name, rows in kv.items():
+        base, leaf = name.rsplit(".", 1)
+        bank = f"{base}.slot{slot}.{leaf}"
+        arr = jnp.asarray(rows, jnp.float32)
+        arr = arr.reshape(arr.shape[-2:])
+        out[bank] = out[bank].at[: arr.shape[0]].set(arr)
+    return out
+
+
+def _assert_bitwise(got, want):
+    assert set(got) == set(want)
+    for name in want:
+        g, w = np.asarray(got[name]), np.asarray(want[name])
+        assert g.dtype == w.dtype == np.float32, name
+        assert np.array_equal(g.view(np.uint32), w.view(np.uint32)), name
+
+
+@pytest.mark.parametrize("arch", ["bert_base", "glm4_9b"])
+@pytest.mark.parametrize("first,second", [(1, 9), (9, 16), (16, 1)])
+def test_slot_writes_equal_the_eager_bank_writes(arch, first, second):
+    """Load slot 1 of three at `first` rows (1, a middle length, the
+    capacity 16), reset it, load it again at `second` from host rows, as
+    a chunked admit passes them: each time the slot's banks are bitwise
+    the eager writes' and the other slots' banks and counters hold."""
+    cfg, params, ncfg, sess = _slot_session(arch, 16)
+    banks = {n: np.asarray(b) for n, b in sess.caches.items()}
+    assert all(np.any(b != 0) for b in banks.values())
+    kv = _exports(cfg, params, ncfg, first)
+    sess.load_slot(1, kv, first)
+    _assert_bitwise(sess.caches, _eager_load(banks, 1, kv))
+    assert sess.pos.tolist() == [2, first, 2]
+    sess.reset_slot(1)
+    _assert_bitwise(sess.caches, _eager_load(banks, 1, {}))
+    assert sess.pos.tolist() == [2, 0, 2]
+    kv = {n: np.asarray(r)
+          for n, r in _exports(cfg, params, ncfg, second).items()}
+    sess.load_slot(1, kv, second)
+    _assert_bitwise(sess.caches, _eager_load(banks, 1, kv))
+    assert sess.pos.tolist() == [2, second, 2]
+
+
+def test_a_slot_write_is_one_donated_dispatch(monkeypatch):
+    """Loads at one length trace the slot writer once, resets once more;
+    each write deletes the slot's old banks and reuses their buffers;
+    the session spans and the trace span name the banks written."""
+    from repro.npec.obs import (EXEC_TRACE, SESSION_LOAD_SLOT,
+                                SESSION_RESET_SLOT)
+
+    cfg, params, ncfg, sess = _slot_session("glm4_9b", 24)
+    kv = _exports(cfg, params, ncfg, 5)
+    opened = []
+    span = exec_mod.span
+
+    def recorded(name, **kw):
+        opened.append((name, kw))
+        return span(name, **kw)
+    recorded.is_enabled = span.is_enabled
+    monkeypatch.setattr(exec_mod, "span", recorded)
+    exec_mod._write_slot.clear_cache()
+
+    def write(fn, slot, *args):
+        names = [n for n in sess.caches if f".slot{slot}." in n]
+        old = [sess.caches[n] for n in names]
+        buffers = {b.unsafe_buffer_pointer() for b in old}
+        fn(slot, *args)
+        assert all(b.is_deleted() for b in old)
+        assert buffers == {sess.caches[n].unsafe_buffer_pointer()
+                           for n in names}
+        return len(names)
+
+    n = write(sess.load_slot, 0, kv, 5)
+    assert n == len(kv) == len(sess.caches) // 3
+    for slot in (1, 0, 2):
+        write(sess.load_slot, slot, kv, 5)
+    for slot in (0, 2):
+        write(sess.reset_slot, slot)
+    assert [kw for name, kw in opened if name == EXEC_TRACE] == [
+        {"rows": 5, "banks": n}, {"rows": 0, "banks": n}]
+    assert [kw for name, kw in opened if name == SESSION_LOAD_SLOT] == [
+        {"slot": s, "rows": 5, "banks": n} for s in (0, 1, 0, 2)]
+    assert [kw for name, kw in opened if name == SESSION_RESET_SLOT] == [
+        {"slot": s, "banks": n} for s in (0, 2)]
+    assert exec_mod._write_slot._cache_size() == 2
