@@ -2,15 +2,33 @@
 
 The tracer is the compiler's front end: it walks a `ModelConfig` and emits
 the per-sequence dataflow graph (repro.npec.ir) that lowering maps onto the
-overlay.  Nothing here is symbolic-execution magic — each family has an
-explicit emitter that mirrors the corresponding jnp module in
-repro.models/*, which is exactly what makes the functional executor
-(repro.npec.exec) checkable against those modules.
+overlay.  Nothing here is symbolic-execution magic — the emitters mirror
+the jnp modules in repro.models/*, which is exactly what makes the
+functional executor (repro.npec.exec) checkable against those modules.
 
-Supported today:
-  * ``bert``   — post-norm encoder (paper Table 1), incl. GQA smoke shapes.
-  * ``dense``  — pre-norm decoder blocks (RoPE + GQA + gated/plain MLP),
-                 full causal attention.
+Every graph is ONE stack walk (`_stack`): `_embed`, then per layer one
+block — post-norm for ``bert`` (paper Table 1), pre-norm for ``dense`` and
+``moe`` — whose attention is ONE emitter (`_attend`) and whose FFN is
+`_ffn`, then the final norm and `_logits_head`.  What differs between
+graphs is the family (`_Dims`) and the phase (`_Rows`), which says where
+the query rows' keys come from:
+  * ``prompt`` — S rows, keys made in the graph, causal or bidirectional
+    (`trace_model`; `trace_prefill`, which also exports each kv head's
+    post-rope k/v under the decode banks' names);
+  * ``slice``  — C prompt rows at a (C,) `pos_ids` input, appended into
+    (T, head_dim) cache banks, each row masked to its own position
+    (`trace_prefill(cache_len=T)`);
+  * ``decode`` — ONE new token per slot at `pos` (a scalar, or a (B,)
+    vector for B > 1 slots sharing one stream), appended into each slot's
+    banks, which are rings under `window` (`trace_decode`), mirroring
+    `models/transformer.decode_step` and the causal
+    `models/bert.decode_step` serving variant op for op.
+
+Families:
+  * ``bert``   — post-norm encoder, incl. GQA smoke shapes; the dims-only
+                 `*_shape` builders trace a `core.cycles.BertShape` through
+                 the same walk (no biases, no layernorm beta).
+  * ``dense``  — pre-norm decoder blocks (RoPE + GQA + gated/plain MLP).
   * ``moe``    — dense blocks whose FFN is a mixture-of-experts every
                  `interleave` layers (granite: all-MoE; llama4:
                  interleaved + shared expert): router logits as an MMU
@@ -20,23 +38,17 @@ Supported today:
                  gated by capacity C = max(1, int(S*k/E * cf)), and
                  the gate-weighted combine — mirroring `models/moe.apply`
                  (including softmax-gate renormalization and
-                 overflow-drop semantics) op for op.
-bert and dense trace in two modes:
-  * prefill (`trace_model`) — the whole sequence at once, per-head
-    QK^T/softmax/AV over (S, S) scores;
-  * decode  (`trace_decode`) — ONE new token against a KV cache of
-    capacity T: skinny (1, H) projections, cache-append of the new k/v,
-    a (1, T) QK^T over the cache, a pos-masked 1xT softmax, and the
-    attention-weighted V reduction — mirroring
-    `models/transformer.decode_step` (and the causal
-    `models/bert.decode_step` serving variant) op for op.
-Unsupported families/features raise `CompileError` naming the gap;
-ROADMAP.md "Open items" tracks the remaining ones.
+                 overflow-drop semantics) op for op.  Prefill only.
+Unsupported families/features raise `CompileError` naming the gap (one
+gate, `_gate`); ROADMAP.md "Open items" tracks the remaining ones.
 
-Heads are traced individually (per-head QK^T/softmax/AV), matching the
-overlay's execution granularity — the schedule-level softmax/matmul overlap
-of paper §7.2.1 then *emerges* in repro.npec.schedule from the dependency
-structure, with no hand-placed pipelining in the emission order.
+Heads are traced individually (per-head QK^T/softmax/AV, or per kv group
+in decode), matching the overlay's execution granularity — the
+schedule-level softmax/matmul overlap of paper §7.2.1 then *emerges* in
+repro.npec.schedule from the dependency structure, with no hand-placed
+pipelining in the emission order.  Tags (`blk3.h5.q`,
+`enc0.kv1.s2.softmax`) are read by repro.npec.schedule and
+repro.npec.fleet.partition, so their spelling is part of the output.
 
 CLI smoke (used by scripts/ci.sh):
     PYTHONPATH=src python -m repro.npec.trace --model bert_base --check
@@ -44,7 +56,7 @@ CLI smoke (used by scripts/ci.sh):
 from __future__ import annotations
 
 import argparse
-from typing import Optional
+from typing import NamedTuple, Optional
 
 from repro.config import ModelConfig
 from repro.npec.ir import Graph, GraphBuilder
@@ -55,157 +67,37 @@ class CompileError(NotImplementedError):
 
 
 # ---------------------------------------------------------------------------
-# BERT (paper Table 1): post-norm encoder
+# The feature gate
 # ---------------------------------------------------------------------------
 
-def _attention(b: GraphBuilder, x: int, l: int, *, S: int, H: int, A: int,
-               KV: int, hd: int, qkv_bias: bool, causal: bool,
-               rope_theta: Optional[float], tag: str,
-               export_kv: bool = False) -> int:
-    """Per-head multi-head attention; returns the output-projection node.
-
-    Heads are emitted in plain dataflow order (q,k,v,qk,softmax,av per
-    head) — deferring the AV matmuls past the next head's projections is
-    the *scheduler's* job, not the tracer's.
-
-    export_kv=True (serving prefill, `trace_prefill`) registers each kv
-    head's post-rope (S, hd) k and v nodes in `Graph.kv_exports` under the
-    decode streams' canonical cache names, so a runtime engine can seed a
-    slot's cache banks from one prefill pass.
-    """
-    g = A // KV
-    kv_nodes = {}
-    z_heads = []
-    for i in range(A):
-        j = i // g                                  # shared kv head (GQA)
-        cq = (i * hd, (i + 1) * hd)
-        ck = (j * hd, (j + 1) * hd)
-        bq = (b.param(("blocks", "bq"), (hd,), layer=l, cols=cq)
-              if qkv_bias else None)
-        q = b.matmul(x, b.param(("blocks", "wq"), (H, hd), layer=l, cols=cq),
-                     bias=bq, tag=f"{tag}.h{i}.q")
-        if rope_theta is not None:
-            q = b.rope(q, theta=rope_theta, tag=f"{tag}.h{i}.q_rope")
-        if j not in kv_nodes:
-            bk = (b.param(("blocks", "bk"), (hd,), layer=l, cols=ck)
-                  if qkv_bias else None)
-            bv = (b.param(("blocks", "bv"), (hd,), layer=l, cols=ck)
-                  if qkv_bias else None)
-            k = b.matmul(x, b.param(("blocks", "wk"), (H, hd), layer=l,
-                                    cols=ck), bias=bk, tag=f"{tag}.h{i}.k")
-            if rope_theta is not None:
-                k = b.rope(k, theta=rope_theta, tag=f"{tag}.h{i}.k_rope")
-            v = b.matmul(x, b.param(("blocks", "wv"), (H, hd), layer=l,
-                                    cols=ck), bias=bv, tag=f"{tag}.h{i}.v")
-            kv_nodes[j] = (k, v)
-            if export_kv:
-                b.g.kv_exports[f"{tag}.kv{j}.k"] = k
-                b.g.kv_exports[f"{tag}.kv{j}.v"] = v
-        k, v = kv_nodes[j]
-        qk = b.matmul(q, k, transpose_b=True, scale=hd ** -0.5,
-                      tag=f"{tag}.h{i}.qk")
-        sm = b.softmax(qk, causal=causal, tag=f"{tag}.h{i}.softmax")
-        z_heads.append(b.matmul(sm, v, tag=f"{tag}.h{i}.av"))
-    z = b.concat(z_heads, tag=f"{tag}.merge_heads")
-    wo = b.param(("blocks", "wo"), (A * hd, H), layer=l)
-    return b.matmul(z, wo, tag=f"{tag}.attn.out")
+_SERVING_FAMILIES = ("bert", "dense")      # families with decode streams
 
 
-def _plain_mlp(b: GraphBuilder, x: int, l: int, *, H: int, F: int,
-               mlp_bias: bool, act: str, tag: str) -> int:
-    """GELU-class two-matmul MLP (bert / plain dense); returns the down
-    projection (pre-residual)."""
-    b1 = (b.param(("blocks", "mlp", "b1"), (F,), layer=l)
-          if mlp_bias else None)
-    ff1 = b.matmul(x, b.param(("blocks", "mlp", "w1"), (H, F), layer=l),
-                   bias=b1, tag=f"{tag}.ff1")
-    mid = b.act(ff1, act, tag=f"{tag}.act")
-    b2 = (b.param(("blocks", "mlp", "b2"), (H,), layer=l)
-          if mlp_bias else None)
-    return b.matmul(mid, b.param(("blocks", "mlp", "w2"), (F, H), layer=l),
-                    bias=b2, tag=f"{tag}.ff2")
-
-
-def _post_norm_rest(b: GraphBuilder, x: int, proj: int, l: int, *, H: int,
-                    F: int, eps: float, mlp_bias: bool, norm_beta: bool,
-                    tag: str) -> int:
-    """The post-norm sandwich after attention (paper Table 1):
-    X2 = LN(X + attn); X4 = MLP(X2); X5 = LN(X2 + X4).  Shared by the
-    prefill, decode, and dims-only BERT paths so the block structure
-    cannot silently diverge between them."""
-    def ln(inp, name, tagname):
-        gamma = b.param(("blocks", name, "gamma"), (H,), layer=l)
-        beta = (b.param(("blocks", name, "beta"), (H,), layer=l)
-                if norm_beta else None)
-        return b.layernorm(inp, gamma, beta, eps=eps, tag=tagname)
-    ln_a = ln(b.add(x, proj, tag=f"{tag}.res_a"), "ln1", f"{tag}.ln_a")
-    ff2 = _plain_mlp(b, ln_a, l, H=H, F=F, mlp_bias=mlp_bias, act="gelu",
-                     tag=tag)
-    res2 = b.add(ln_a, ff2, tag=f"{tag}.res_b")
-    return ln(res2, "ln2", f"{tag}.ln_b")
-
-
-def _bert_layer(b: GraphBuilder, x: int, l: int, *, S: int, H: int, A: int,
-                KV: int, hd: int, F: int, eps: float, qkv_bias: bool,
-                mlp_bias: bool, tag: str, causal: bool = False,
-                export_kv: bool = False) -> int:
-    proj = _attention(b, x, l, S=S, H=H, A=A, KV=KV, hd=hd,
-                      qkv_bias=qkv_bias, causal=causal, rope_theta=None,
-                      tag=tag, export_kv=export_kv)
-    return _post_norm_rest(b, x, proj, l, H=H, F=F, eps=eps,
-                           mlp_bias=mlp_bias, norm_beta=True, tag=tag)
-
-
-def _trace_bert(cfg: ModelConfig, seq: int, layers: Optional[int],
-                include_embed: bool, *, causal: bool = False,
-                logits_head: bool = False, export_kv: bool = False) -> Graph:
-    """causal/logits_head/export_kv are the *serving prefill* variant
-    (`trace_prefill`): causal masking + a vocab head + kv exports mirror
-    what an incremental `models/bert.decode_step` rollout over the prompt
-    computes — the bidirectional default is the paper's encoder."""
-    b = GraphBuilder()
-    S, H, A, KV = seq, cfg.d_model, cfg.num_heads, cfg.num_kv_heads
-    hd, F = cfg.head_dim, cfg.d_ff
-    L = layers if layers is not None else cfg.num_layers
-    if include_embed:
-        tokens = b.input("tokens", (S,), dtype="int32")
-        x = b.embed(tokens, b.param(("embed",), (cfg.vocab_size, H)),
-                    tag="embed.tok")
-        x = b.add(x, b.param(("pos_embed",), (S, H), rows=(0, S)),
-                  tag="embed.pos")
-        x = b.add(x, b.param(("type_embed",), (H,), index=0),
-                  tag="embed.type")
-        x = b.layernorm(x, b.param(("ln_embed", "gamma"), (H,)),
-                        b.param(("ln_embed", "beta"), (H,)),
-                        eps=1e-12, tag="embed.ln")
-    else:
-        x = b.input("x", (S, H))
-    for l in range(L):
-        x = _bert_layer(b, x, l, S=S, H=H, A=A, KV=KV, hd=hd, F=F,
-                        eps=1e-12, qkv_bias=cfg.qkv_bias,
-                        mlp_bias=cfg.mlp_bias, tag=f"enc{l}",
-                        causal=causal, export_kv=export_kv)
-    if logits_head and include_embed:
-        x = _logits_head(b, cfg, x)
-    b.output(x)
-    return b.g
-
-
-# ---------------------------------------------------------------------------
-# Dense decoder family (pre-norm GQA + gated/plain MLP)
-# ---------------------------------------------------------------------------
-
-def _check_block_supported(cfg: ModelConfig, *, moe_ok: bool = False,
-                           window_ok: bool = False) -> None:
-    """Feature gates shared by the dense and moe families; `moe_ok` lets
-    the moe tracer accept the MoE config it exists to lower, `window_ok`
-    lets the windowed decode tracers accept "sliding" attention (a ring
-    cache of capacity cfg.window IS sliding-window attention — see
-    `trace_decode(window=True)`)."""
+def _gate(cfg: ModelConfig, phase: str, *, window: bool = False) -> None:
+    """Raise CompileError naming what npec cannot lower of `cfg` for
+    `phase` ("model": `trace_model`; "prefill"/"decode": the two halves of
+    serving).  window=True lifts the "sliding"-attention gap: a ring cache
+    of capacity cfg.window IS sliding-window attention."""
+    fam, name = cfg.family, cfg.name
+    if phase == "model" and fam not in ("bert", "dense", "moe"):
+        raise CompileError(
+            f"npec has no tracer for family {fam!r} ({name!r}) "
+            "yet (see ROADMAP.md Open items)")
+    if phase != "model" and fam not in _SERVING_FAMILIES:
+        gap = ("MoE decode streams (per-token capacity-1 dispatch)"
+               if fam == "moe" else f"decode streams for family {fam!r}")
+        why = ", so it cannot serve this family" if phase == "prefill" else ""
+        raise CompileError(f"npec cannot lower {gap} yet ({name!r}){why} "
+                           "(see ROADMAP.md Open items)")
+    if fam == "bert":
+        return
+    if phase == "prefill" and not cfg.causal:
+        raise CompileError(f"npec serving prefill needs a causal model; "
+                           f"{name!r} is bidirectional")
     attn_gap = (cfg.attention != "full"
-                and not (window_ok and cfg.attention == "sliding"))
+                and not (window and cfg.attention == "sliding"))
     for feat, msg in (
-            (cfg.moe is not None and not moe_ok, "MoE routing"),
+            (cfg.moe is not None and fam != "moe", "MoE routing"),
             (attn_gap, f"{cfg.attention!r} attention streams"),
             (cfg.parallel_block, "parallel attn+mlp blocks"),
             (cfg.qk_norm, "per-head qk-norm"),
@@ -216,82 +108,324 @@ def _check_block_supported(cfg: ModelConfig, *, moe_ok: bool = False,
     ):
         if feat:
             raise CompileError(
-                f"npec cannot lower {msg} yet for {cfg.name!r} "
+                f"npec cannot lower {msg} yet for {name!r} "
                 "(see ROADMAP.md Open items)")
 
 
-def _check_dense_supported(cfg: ModelConfig, *,
-                           window_ok: bool = False) -> None:
-    _check_block_supported(cfg, moe_ok=False, window_ok=window_ok)
+# ---------------------------------------------------------------------------
+# What a graph is traced from: the model's dims and the phase's rows
+# ---------------------------------------------------------------------------
+
+class _Dims(NamedTuple):
+    """What the stack walk reads of a model: a ModelConfig (`_dims`) or a
+    raw `core.cycles.BertShape` (`_shape_dims`, which has no embedding or
+    head, so `cfg` is None)."""
+    cfg: Optional[ModelConfig]
+    post_norm: bool               # bert's block; pre-norm otherwise
+    H: int
+    A: int
+    KV: int
+    hd: int
+    F: int
+    qkv_bias: bool
+    mlp_bias: bool
+    norm: str                     # "layernorm" | anything else: rmsnorm
+    norm_beta: bool
+    eps: float
+    gated: bool
+    act: str
+    theta: Optional[float]        # RoPE base, None without rope
 
 
-def _trace_dense(cfg: ModelConfig, seq: int, layers: Optional[int],
-                 include_embed: bool, *, export_kv: bool = False,
-                 window_ok: bool = False) -> Graph:
-    _check_dense_supported(cfg, window_ok=window_ok)
-    b = GraphBuilder()
-    S, H, A, KV = seq, cfg.d_model, cfg.num_heads, cfg.num_kv_heads
-    hd, F = cfg.head_dim, cfg.d_ff
-    L = layers if layers is not None else cfg.num_layers
-    theta = cfg.rope_theta if cfg.rope == "standard" else None
+def _dims(cfg: ModelConfig) -> _Dims:
+    """bert blocks are layernorm(eps=1e-12, with beta) + plain GELU MLP
+    whatever the config says; dense/moe follow `models/common.apply_norm`
+    at its default eps=1e-6."""
+    bert = cfg.family == "bert"
+    return _Dims(
+        cfg, bert, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+        cfg.head_dim, cfg.d_ff, cfg.qkv_bias, cfg.mlp_bias,
+        "layernorm" if bert else cfg.norm, bert or cfg.norm_bias,
+        1e-12 if bert else 1e-6, not bert and cfg.mlp_type == "gated",
+        "gelu" if bert else cfg.activation,
+        cfg.rope_theta if cfg.rope == "standard" and not bert else None)
 
-    def norm(x, path, layer, tag):
-        return _dense_norm(b, cfg, x, path, layer, tag)
-    if include_embed:
-        tokens = b.input("tokens", (S,), dtype="int32")
-        x = b.embed(tokens, b.param(("embed",), (cfg.vocab_size, H)),
-                    tag="embed.tok")
+
+def _shape_dims(shape, *, norm_beta: bool = False) -> _Dims:
+    """A BertShape's encoder with no biases (bias adds are folded and cost
+    nothing, so the instruction stream is cycle-identical either way);
+    its block layernorms carry beta only in `trace_bert_shape`."""
+    return _Dims(None, True, shape.hidden, shape.heads, shape.heads,
+                 shape.head_dim, shape.d_ff, False, False, "layernorm",
+                 norm_beta, 1e-12, False, "gelu", None)
+
+
+class _Rows(NamedTuple):
+    """The graph's query rows and where their keys come from (module
+    docstring).  `pos` / `pos_slots` are filled in by `_stack`: the
+    slice's `pos_ids` or decode's `pos` input node, and for B > 1 decode
+    slots the hoisted per-slot scalar selects of `pos` that mask each
+    slot's softmax."""
+    phase: str                    # "prompt" | "slice" | "decode"
+    n: int                        # query rows: S, C or B
+    T: int = 0                    # cache-bank capacity (slice, decode)
+    causal: bool = False          # prompt mask
+    export_kv: bool = False       # prompt: export k/v for decode banks
+    window: bool = False          # decode: ring banks
+    pos: Optional[int] = None
+    pos_slots: tuple = ()
+
+
+# ---------------------------------------------------------------------------
+# The one attention emitter
+# ---------------------------------------------------------------------------
+
+def _attend(b: GraphBuilder, x: int, l: int, d: _Dims, rows: _Rows,
+            tag: str) -> int:
+    """Attention of layer `l` over hidden state `x`; returns the output
+    projection node.  It walks the kv groups and their query heads once.
+
+    * prompt: per query head q, QK^T, softmax (causal or not) and AV; the
+      group's k/v are projected right after its first head's q and
+      tagged by that head (`h{i}.k`), and optionally exported as
+      `{tag}.kv{j}.k/v`.  Heads are emitted in plain dataflow order —
+      deferring the AV matmuls past the next head's projections is the
+      *scheduler's* job, not the tracer's.
+    * slice: the group's (C, hd) k/v (post-rope at `pos_ids`) burst-append
+      into its bank (`cache_append` rows=C), then each query head runs a
+      (C, T) QK^T over the *updated* bank with a row-masked softmax (row r
+      attends to slots <= pos_ids[r]: same-slice future keys are in the
+      bank but masked), which is what makes the chunked path bitwise-equal
+      to the whole prompt in float mode.
+    * decode: the new k/v append into each slot's bank at `pos`; the
+      group's g skinny query rows stack into one (g, T) QK^T, pos-masked
+      softmax and AV per slot — how GQA decode amortizes the cache read,
+      in lockstep with the grouped einsum of models/common.attention_scores.
+      B > 1 slots share every weight projection as one merged B-row MMU
+      tile (rope rotates row s at pos[s]); each slot keeps its own bank
+      (`{tag}.kv{j}.slot{s}.k/v`) and its own attention stream, selected
+      by `slot_select`, since every slot attends to a different cache.
+      Under `window` each bank is a ring: the append wraps at T and the
+      pos-masked softmax saturates to the full ring once pos >= T, so the
+      QK^T tile stays (g, T) with T the window, never the full context.
+    """
+    H, hd, g = d.H, d.hd, d.A // d.KV
+    prompt, decode = rows.phase == "prompt", rows.phase == "decode"
+    B = rows.n if decode else 1
+
+    def rope(n, t):
+        return (n if d.theta is None
+                else b.rope(n, theta=d.theta, pos=rows.pos, tag=t))
+
+    def q_proj(i):
+        c = (i * hd, (i + 1) * hd)
+        bq = (b.param(("blocks", "bq"), (hd,), layer=l, cols=c)
+              if d.qkv_bias else None)
+        q = b.matmul(x, b.param(("blocks", "wq"), (H, hd), layer=l, cols=c),
+                     bias=bq, tag=f"{tag}.h{i}.q")
+        return rope(q, f"{tag}.h{i}.q_rope")
+
+    def kv_proj(j, t):
+        c = (j * hd, (j + 1) * hd)
+        bk, bv = ((b.param(("blocks", "bk"), (hd,), layer=l, cols=c),
+                   b.param(("blocks", "bv"), (hd,), layer=l, cols=c))
+                  if d.qkv_bias else (None, None))
+        k = b.matmul(x, b.param(("blocks", "wk"), (H, hd), layer=l, cols=c),
+                     bias=bk, tag=f"{t}.k")
+        k = rope(k, f"{t}.k_rope")
+        v = b.matmul(x, b.param(("blocks", "wv"), (H, hd), layer=l, cols=c),
+                     bias=bv, tag=f"{t}.v")
+        return k, v
+
+    def scores(qs, k, v, valid, t):
+        """QK^T/softmax/AV of one head, or of a stacked decode group."""
+        qg = qs[0] if len(qs) == 1 else b.concat(qs, axis=-2,
+                                                 tag=f"{t}.qstack")
+        qk = b.matmul(qg, k, transpose_b=True, scale=hd ** -0.5,
+                      tag=f"{t}.qk")
+        sm = b.softmax(qk, causal=rows.causal, valid_upto=valid,
+                       tag=f"{t}.softmax")
+        av = b.matmul(sm, v, tag=f"{t}.av")
+        return (av if len(qs) == 1
+                else b.reshape(av, (1, len(qs) * hd), tag=f"{t}.flatten"))
+
+    z = [[] for _ in range(B)]               # slot -> per-head/group rows
+    for j in range(d.KV):
+        heads = range(j * g, (j + 1) * g)
+        if prompt:
+            for i in heads:
+                q = q_proj(i)
+                if i == j * g:
+                    k, v = kv_proj(j, f"{tag}.h{i}")
+                    if rows.export_kv:
+                        b.g.kv_exports[f"{tag}.kv{j}.k"] = k
+                        b.g.kv_exports[f"{tag}.kv{j}.v"] = v
+                z[0].append(scores([q], k, v, None, f"{tag}.h{i}"))
+            continue
+        k, v = kv_proj(j, f"{tag}.kv{j}")
+        banks = []
+        for s in range(B):
+            name = f"{tag}.kv{j}" + (f".slot{s}" if B > 1 else "")
+            kc = b.cache(f"{name}.k", (rows.T, hd))
+            vc = b.cache(f"{name}.v", (rows.T, hd))
+            slot = s if B > 1 else None
+            banks.append((
+                b.cache_append(kc, k, rows.pos, slot=slot,
+                               window=rows.window),
+                b.cache_append(vc, v, rows.pos, slot=slot,
+                               window=rows.window)))
+        if not decode:
+            kc, vc = banks[0]
+            for i in heads:
+                z[0].append(scores([q_proj(i)], kc, vc, rows.pos,
+                                   f"{tag}.h{i}"))
+            continue
+        qs = [q_proj(i) for i in heads]
+        for s, (kc, vc) in enumerate(banks):
+            if B == 1:
+                z[0].append(scores(qs, kc, vc, rows.pos, f"{tag}.kv{j}"))
+                continue
+            t = f"{tag}.kv{j}.s{s}"
+            rows_s = [b.slot_select(q, s, tag=f"{t}.q{gi}")
+                      for gi, q in enumerate(qs)]
+            z[s].append(scores(rows_s, kc, vc, rows.pos_slots[s], t))
+    if not decode:
+        zz = b.concat(z[0], tag=f"{tag}.merge_heads")
     else:
-        x = b.input("x", (S, H))
-    for l in range(L):
+        per_slot = [p[0] if len(p) == 1 else b.concat(
+            p, tag=f"{tag}{f'.s{s}' if B > 1 else ''}.merge_heads")
+            for s, p in enumerate(z)]
+        zz = (per_slot[0] if B == 1
+              else b.concat(per_slot, axis=-2, tag=f"{tag}.merge_slots"))
+    wo = b.param(("blocks", "wo"), (d.A * hd, H), layer=l)
+    return b.matmul(zz, wo, tag=f"{tag}.attn.out")
+
+
+# ---------------------------------------------------------------------------
+# The one stack walk: embed -> blocks (attention + FFN) -> norm -> head
+# ---------------------------------------------------------------------------
+
+def _norm(b: GraphBuilder, d: _Dims, x: int, path, layer, tag: str) -> int:
+    gamma = b.param(tuple(path) + ("gamma",), (d.H,), layer=layer)
+    if d.norm == "layernorm":
+        beta = (b.param(tuple(path) + ("beta",), (d.H,), layer=layer)
+                if d.norm_beta else None)
+        return b.layernorm(x, gamma, beta, eps=d.eps, tag=tag)
+    return b.rmsnorm(x, gamma, eps=d.eps, tag=tag)
+
+
+def _embed(b: GraphBuilder, d: _Dims, rows: _Rows) -> int:
+    """Token embedding; bert adds its learned positions (the prompt's
+    first S rows, or gathered at `pos` / `pos_ids`), the type embedding
+    and the embedding layernorm."""
+    cfg, H = d.cfg, d.H
+    tokens = b.input("tokens", (rows.n,), dtype="int32")
+    x = b.embed(tokens, b.param(("embed",), (cfg.vocab_size, H)),
+                tag="embed.tok")
+    if not d.post_norm:
+        return x
+    if rows.phase == "prompt":
+        x = b.add(x, b.param(("pos_embed",), (rows.n, H), rows=(0, rows.n)),
+                  tag="embed.pos")
+    else:
+        pe = b.embed(rows.pos, b.param(("pos_embed",),
+                                       (cfg.max_position, H)),
+                     tag="embed.pos")
+        x = b.add(x, pe, tag="embed.pos_add")
+    x = b.add(x, b.param(("type_embed",), (H,), index=0), tag="embed.type")
+    return _norm(b, d, x, ("ln_embed",), None, "embed.ln")
+
+
+def _mlp(b: GraphBuilder, d: _Dims, x: int, l: int, tag: str) -> int:
+    """Gated (SwiGLU/GeGLU) or plain two-matmul MLP; returns the down
+    projection (pre-residual)."""
+    def w(name, shape):
+        return b.param(("blocks", "mlp", name), shape, layer=l)
+    H, F = d.H, d.F
+    if d.gated:
+        gt = b.act(b.matmul(x, w("wg", (H, F)), tag=f"{tag}.ffg"), d.act,
+                   tag=f"{tag}.act")
+        up = b.matmul(x, w("wu", (H, F)), tag=f"{tag}.ffu")
+        return b.matmul(b.mul(gt, up, tag=f"{tag}.gate"), w("wd", (F, H)),
+                        tag=f"{tag}.ffd")
+    b1 = w("b1", (F,)) if d.mlp_bias else None
+    mid = b.act(b.matmul(x, w("w1", (H, F)), bias=b1, tag=f"{tag}.ff1"),
+                d.act, tag=f"{tag}.act")
+    b2 = w("b2", (H,)) if d.mlp_bias else None
+    return b.matmul(mid, w("w2", (F, H)), bias=b2, tag=f"{tag}.ff2")
+
+
+def _ffn(b: GraphBuilder, d: _Dims, x: int, l: int, tag: str) -> int:
+    """Layer l's FFN: MoE iff (l+1) % interleave == 0
+    (`models/transformer.layer_is_moe`), the MLP otherwise.  The two kinds
+    keep separate stacked params, indexed by how many layers of that kind
+    came before l."""
+    m = d.cfg.moe if d.cfg is not None else None
+    if m is None:
+        return _mlp(b, d, x, l, tag)
+    if (l + 1) % m.interleave == 0:
+        return _moe_ffn(b, d.cfg, x, l // m.interleave,
+                        S=b.g.node(x).shape[0], tag=tag)[0]
+    return _mlp(b, d, x, l - l // m.interleave, tag)
+
+
+def _logits_head(b: GraphBuilder, cfg: ModelConfig, x: int) -> int:
+    """Final vocab projection: tied configs reuse the (V, H) embedding
+    table transposed (still MMU-resident), untied use lm_head (H, V)."""
+    V, H = cfg.vocab_size, cfg.d_model
+    if cfg.tie_embeddings or cfg.family == "bert":
+        return b.matmul(x, b.param(("embed",), (V, H)), transpose_b=True,
+                        tag="logits")
+    return b.matmul(x, b.param(("lm_head",), (H, V)), tag="logits")
+
+
+def _stack(d: _Dims, rows: _Rows, layers: Optional[int],
+           include_embed: bool, *, head: bool) -> Graph:
+    """The graph of `layers` blocks (all of the model's for None) over
+    `rows`: post-norm bert blocks (X2 = LN(X + attn); X5 = LN(X2 +
+    MLP(X2)), tagged `enc{l}`) or pre-norm blocks (X + attn(LN(X)), then
+    X + FFN(LN(X)), tagged `blk{l}`, closed by the final norm `ln_f`).
+    include_embed=False starts from an (n, H) hidden-state input `x`;
+    head=True ends in the vocab logits."""
+    if layers is None:
+        layers = d.cfg.num_layers
+    b = GraphBuilder()
+    if rows.phase == "slice":
+        rows = rows._replace(pos=b.input("pos_ids", (rows.n,),
+                                         dtype="int32"))
+    elif rows.phase == "decode" and rows.n == 1:
+        rows = rows._replace(pos=b.input("pos", (), dtype="int32"))
+    elif rows.phase == "decode":
+        pos = b.input("pos", (rows.n,), dtype="int32")
+        rows = rows._replace(pos=pos, pos_slots=tuple(
+            b.slot_select(pos, s, tag=f"pos.s{s}") for s in range(rows.n)))
+    x = (_embed(b, d, rows) if include_embed
+         else b.input("x", (rows.n, d.H)))
+    for l in range(layers):
+        if d.post_norm:
+            tag = f"enc{l}"
+            x = _norm(b, d, b.add(x, _attend(b, x, l, d, rows, tag),
+                                  tag=f"{tag}.res_a"),
+                      ("blocks", "ln1"), l, f"{tag}.ln_a")
+            x = _norm(b, d, b.add(x, _ffn(b, d, x, l, tag),
+                                  tag=f"{tag}.res_b"),
+                      ("blocks", "ln2"), l, f"{tag}.ln_b")
+            continue
         tag = f"blk{l}"
-        h = norm(x, ("blocks", "ln1"), l, f"{tag}.ln1")
-        attn = _attention(b, h, l, S=S, H=H, A=A, KV=KV, hd=hd,
-                          qkv_bias=cfg.qkv_bias, causal=cfg.causal,
-                          rope_theta=theta, tag=tag, export_kv=export_kv)
-        x = b.add(x, attn, tag=f"{tag}.res_a")
-        h2 = norm(x, ("blocks", "ln2"), l, f"{tag}.ln2")
-        down = _dense_mlp(b, cfg, h2, l, H=H, F=F, tag=tag)
-        x = b.add(x, down, tag=f"{tag}.res_b")
-    x = norm(x, ("ln_f",), None, "ln_f")
-    if include_embed:
-        x = _logits_head(b, cfg, x)
+        h = _norm(b, d, x, ("blocks", "ln1"), l, f"{tag}.ln1")
+        x = b.add(x, _attend(b, h, l, d, rows, tag), tag=f"{tag}.res_a")
+        h = _norm(b, d, x, ("blocks", "ln2"), l, f"{tag}.ln2")
+        x = b.add(x, _ffn(b, d, h, l, tag), tag=f"{tag}.res_b")
+    if not d.post_norm:
+        x = _norm(b, d, x, ("ln_f",), None, "ln_f")
+    if head:
+        x = _logits_head(b, d.cfg, x)
     b.output(x)
     return b.g
 
 
-def _dense_mlp(b: GraphBuilder, cfg: ModelConfig, h2: int, l: int, *,
-               H: int, F: int, tag: str) -> int:
-    """Gated (SwiGLU/GeGLU) or plain MLP for the dense family; returns the
-    down projection (pre-residual)."""
-    if cfg.mlp_type == "gated":
-        gt = b.act(b.matmul(
-            h2, b.param(("blocks", "mlp", "wg"), (H, F), layer=l),
-            tag=f"{tag}.ffg"), cfg.activation, tag=f"{tag}.act")
-        up = b.matmul(h2, b.param(("blocks", "mlp", "wu"), (H, F),
-                                  layer=l), tag=f"{tag}.ffu")
-        hmid = b.mul(gt, up, tag=f"{tag}.gate")
-        return b.matmul(hmid, b.param(("blocks", "mlp", "wd"), (F, H),
-                                      layer=l), tag=f"{tag}.ffd")
-    return _plain_mlp(b, h2, l, H=H, F=F, mlp_bias=cfg.mlp_bias,
-                      act=cfg.activation, tag=tag)
-
-
-def _dense_norm(b: GraphBuilder, cfg: ModelConfig, x: int, path, layer,
-                tag: str) -> int:
-    """models/common.py::apply_norm at its default eps=1e-6, including the
-    beta parameter when the config carries one."""
-    H = cfg.d_model
-    gamma = b.param(tuple(path) + ("gamma",), (H,), layer=layer)
-    if cfg.norm == "layernorm":
-        beta = (b.param(tuple(path) + ("beta",), (H,), layer=layer)
-                if cfg.norm_bias else None)
-        return b.layernorm(x, gamma, beta, eps=1e-6, tag=tag)
-    return b.rmsnorm(x, gamma, eps=1e-6, tag=tag)
-
-
 # ---------------------------------------------------------------------------
-# MoE family (granite: every layer; llama4: every `interleave`-th layer)
+# MoE FFN (granite: every layer; llama4: every `interleave`-th layer)
 # ---------------------------------------------------------------------------
 
 def moe_capacity(cfg: ModelConfig, seq: int) -> int:
@@ -361,48 +495,6 @@ def _moe_ffn(b: GraphBuilder, cfg: ModelConfig, x: int, mi: int, *, S: int,
     return out, aux
 
 
-def _trace_moe(cfg: ModelConfig, seq: int, layers: Optional[int],
-               include_embed: bool) -> Graph:
-    """Pre-norm decoder stack whose FFN is MoE on every `interleave`-th
-    layer (`models/transformer.layer_is_moe` pattern: layer l is MoE iff
-    (l+1) % interleave == 0) and a dense MLP otherwise — mirroring
-    `models/transformer.apply` for family "moe"."""
-    _check_block_supported(cfg, moe_ok=True)
-    b = GraphBuilder()
-    S, H, A, KV = seq, cfg.d_model, cfg.num_heads, cfg.num_kv_heads
-    hd, F = cfg.head_dim, cfg.d_ff
-    L = layers if layers is not None else cfg.num_layers
-    theta = cfg.rope_theta if cfg.rope == "standard" else None
-    step = cfg.moe.interleave
-    if include_embed:
-        tokens = b.input("tokens", (S,), dtype="int32")
-        x = b.embed(tokens, b.param(("embed",), (cfg.vocab_size, H)),
-                    tag="embed.tok")
-    else:
-        x = b.input("x", (S, H))
-    mi = di = 0                      # moe / dense-mlp stacked-param indices
-    for l in range(L):
-        tag = f"blk{l}"
-        h = _dense_norm(b, cfg, x, ("blocks", "ln1"), l, f"{tag}.ln1")
-        attn = _attention(b, h, l, S=S, H=H, A=A, KV=KV, hd=hd,
-                          qkv_bias=cfg.qkv_bias, causal=cfg.causal,
-                          rope_theta=theta, tag=tag)
-        x = b.add(x, attn, tag=f"{tag}.res_a")
-        h2 = _dense_norm(b, cfg, x, ("blocks", "ln2"), l, f"{tag}.ln2")
-        if (l + 1) % step == 0:
-            down, _ = _moe_ffn(b, cfg, h2, mi, S=S, tag=tag)
-            mi += 1
-        else:
-            down = _dense_mlp(b, cfg, h2, di, H=H, F=F, tag=tag)
-            di += 1
-        x = b.add(x, down, tag=f"{tag}.res_b")
-    x = _dense_norm(b, cfg, x, ("ln_f",), None, "ln_f")
-    if include_embed:
-        x = _logits_head(b, cfg, x)
-    b.output(x)
-    return b.g
-
-
 def trace_moe_block(cfg: ModelConfig, seq: int, *, layer: int = 0,
                     debug_outputs: bool = False) -> Graph:
     """Graph of ONE MoE FFN block over an (S, D) hidden-state input — the
@@ -425,22 +517,21 @@ def trace_moe_block(cfg: ModelConfig, seq: int, *, layer: int = 0,
 # Entry points
 # ---------------------------------------------------------------------------
 
-_TRACERS = {"bert": _trace_bert, "dense": _trace_dense, "moe": _trace_moe}
-
-
 def trace_model(cfg: ModelConfig, seq: int, *, layers: Optional[int] = None,
                 include_embed: bool = True) -> Graph:
     """Emit the IR graph for `cfg` at sequence length `seq`.
 
     layers=N truncates the stack (cycle models usually compile one layer
     and scale); include_embed=False starts from a hidden-state input.
+    bert traces the paper's bidirectional encoder, which ends at its last
+    layernorm; dense and moe trace their causal stack and vocab head.
     """
-    tracer = _TRACERS.get(cfg.family)
-    if tracer is None:
-        raise CompileError(
-            f"npec has no tracer for family {cfg.family!r} ({cfg.name!r}) "
-            "yet (see ROADMAP.md Open items)")
-    return tracer(cfg, seq, layers, include_embed)
+    _gate(cfg, "model")
+    bert = cfg.family == "bert"
+    return _stack(_dims(cfg), _Rows("prompt", seq,
+                                    causal=not bert and cfg.causal),
+                  layers, include_embed,
+                  head=include_embed and not bert)
 
 
 def trace_bert_shape(shape, *, layers: int = 1) -> Graph:
@@ -448,268 +539,8 @@ def trace_bert_shape(shape, *, layers: int = 1) -> Graph:
     dims-only path `core.cycles` uses as its npec backend (no ModelConfig,
     no biases: bias adds are folded and cost nothing, so the instruction
     stream is cycle-identical either way)."""
-    b = GraphBuilder()
-    x = b.input("x", (shape.seq, shape.hidden))
-    for l in range(layers):
-        x = _bert_layer(b, x, l, S=shape.seq, H=shape.hidden,
-                        A=shape.heads, KV=shape.heads, hd=shape.head_dim,
-                        F=shape.d_ff, eps=1e-12, qkv_bias=False,
-                        mlp_bias=False, tag=f"enc{l}")
-    b.output(x)
-    return b.g
-
-
-# ---------------------------------------------------------------------------
-# Decode-step tracers: one new token over a KV cache of capacity T
-# ---------------------------------------------------------------------------
-
-def _decode_attention(b: GraphBuilder, x: int, l: int, *, T: int, H: int,
-                      A: int, KV: int, hd: int, qkv_bias: bool,
-                      rope_theta: Optional[float], pos: int,
-                      tag: str, B: int = 1,
-                      pos_slots: Optional[list] = None,
-                      window: bool = False) -> int:
-    """Cached one-token attention; returns the output-projection node.
-
-    Per kv head: the new k/v appended into the (T, hd) cache at `pos`
-    (MWU traffic, folded), the group's skinny (1, H) q projections (127
-    of the 128 MMU PE rows idle — reported by the lowering's tiling
-    metadata) stacked into (g, hd), a (g, T) QK^T over the cache, a
-    pos-masked softmax, and the attention-weighted V reduction.  Grouping
-    the query heads of one kv head into a single QK^T/AV stream is how
-    GQA decode actually amortizes the cache read — and it keeps the
-    executor numerically in lockstep with the grouped einsum in
-    models/common.attention_scores.
-
-    B > 1 is the *batched* decode stream (repro.npec.runtime): B serving
-    slots share one stream, so every weight projection is a single merged
-    B-row MMU tile (occupancy ~B/128 instead of ~1/128) over the stacked
-    slot states, `pos` is a (B,) vector (rope rotates row s at pos[s]),
-    and each slot keeps its own cache bank (`{tag}.kv{j}.slot{s}.k/v`)
-    with its own pos-masked QK^T/softmax/AV stream — attention cannot
-    merge across slots because every slot attends to a different cache.
-    `pos_slots[s]` is the hoisted scalar slot_select of pos for softmax
-    masking.
-
-    window=True makes every cache bank a ring (sliding-window attention):
-    the append wraps at T and the pos-masked softmax saturates to the full
-    T-slot ring once pos >= T — the QK^T tile stays (g, T) with T = the
-    window length, never the full context, which is the banded-tile win.
-    """
-    g = A // KV
-    if B > 1:
-        return _decode_attention_batched(
-            b, x, l, T=T, H=H, A=A, KV=KV, hd=hd, qkv_bias=qkv_bias,
-            rope_theta=rope_theta, pos=pos, pos_slots=pos_slots, tag=tag,
-            B=B, window=window)
-    z_groups = []
-    for j in range(KV):
-        ck = (j * hd, (j + 1) * hd)
-        bk = (b.param(("blocks", "bk"), (hd,), layer=l, cols=ck)
-              if qkv_bias else None)
-        bv = (b.param(("blocks", "bv"), (hd,), layer=l, cols=ck)
-              if qkv_bias else None)
-        k = b.matmul(x, b.param(("blocks", "wk"), (H, hd), layer=l,
-                                cols=ck), bias=bk, tag=f"{tag}.kv{j}.k")
-        if rope_theta is not None:
-            k = b.rope(k, theta=rope_theta, pos=pos,
-                       tag=f"{tag}.kv{j}.k_rope")
-        v = b.matmul(x, b.param(("blocks", "wv"), (H, hd), layer=l,
-                                cols=ck), bias=bv, tag=f"{tag}.kv{j}.v")
-        kc = b.cache(f"{tag}.kv{j}.k", (T, hd))
-        vc = b.cache(f"{tag}.kv{j}.v", (T, hd))
-        kc = b.cache_append(kc, k, pos, window=window)
-        vc = b.cache_append(vc, v, pos, window=window)
-        q_heads = []
-        for gi in range(g):
-            i = j * g + gi
-            cq = (i * hd, (i + 1) * hd)
-            bq = (b.param(("blocks", "bq"), (hd,), layer=l, cols=cq)
-                  if qkv_bias else None)
-            q = b.matmul(x, b.param(("blocks", "wq"), (H, hd), layer=l,
-                                    cols=cq), bias=bq, tag=f"{tag}.h{i}.q")
-            if rope_theta is not None:
-                q = b.rope(q, theta=rope_theta, pos=pos,
-                           tag=f"{tag}.h{i}.q_rope")
-            q_heads.append(q)
-        qg = (q_heads[0] if g == 1
-              else b.concat(q_heads, axis=-2, tag=f"{tag}.kv{j}.qstack"))
-        qk = b.matmul(qg, kc, transpose_b=True, scale=hd ** -0.5,
-                      tag=f"{tag}.kv{j}.qk")
-        sm = b.softmax(qk, valid_upto=pos, tag=f"{tag}.kv{j}.softmax")
-        av = b.matmul(sm, vc, tag=f"{tag}.kv{j}.av")
-        z_groups.append(av if g == 1
-                        else b.reshape(av, (1, g * hd),
-                                       tag=f"{tag}.kv{j}.flatten"))
-    z = (z_groups[0] if len(z_groups) == 1
-         else b.concat(z_groups, tag=f"{tag}.merge_heads"))
-    wo = b.param(("blocks", "wo"), (A * hd, H), layer=l)
-    return b.matmul(z, wo, tag=f"{tag}.attn.out")
-
-
-def _decode_attention_batched(b: GraphBuilder, x: int, l: int, *, T: int,
-                              H: int, A: int, KV: int, hd: int,
-                              qkv_bias: bool, rope_theta: Optional[float],
-                              pos: int, pos_slots: list, tag: str,
-                              B: int, window: bool = False) -> int:
-    """B-slot cached attention over a merged (B, H) hidden state: merged
-    B-row k/v/q projections, per-slot cache banks + masked attention
-    streams, and a merged B-row output projection.  See _decode_attention.
-    """
-    g = A // KV
-    z_parts: list = [[] for _ in range(B)]      # slot -> per-kv-head rows
-    for j in range(KV):
-        ck = (j * hd, (j + 1) * hd)
-        bk = (b.param(("blocks", "bk"), (hd,), layer=l, cols=ck)
-              if qkv_bias else None)
-        bv = (b.param(("blocks", "bv"), (hd,), layer=l, cols=ck)
-              if qkv_bias else None)
-        k = b.matmul(x, b.param(("blocks", "wk"), (H, hd), layer=l,
-                                cols=ck), bias=bk, tag=f"{tag}.kv{j}.k")
-        if rope_theta is not None:
-            k = b.rope(k, theta=rope_theta, pos=pos,
-                       tag=f"{tag}.kv{j}.k_rope")
-        v = b.matmul(x, b.param(("blocks", "wv"), (H, hd), layer=l,
-                                cols=ck), bias=bv, tag=f"{tag}.kv{j}.v")
-        banks = []
-        for s in range(B):
-            kc = b.cache(f"{tag}.kv{j}.slot{s}.k", (T, hd))
-            vc = b.cache(f"{tag}.kv{j}.slot{s}.v", (T, hd))
-            kc = b.cache_append(kc, k, pos, slot=s, window=window)
-            vc = b.cache_append(vc, v, pos, slot=s, window=window)
-            banks.append((kc, vc))
-        q_heads = []
-        for gi in range(g):
-            i = j * g + gi
-            cq = (i * hd, (i + 1) * hd)
-            bq = (b.param(("blocks", "bq"), (hd,), layer=l, cols=cq)
-                  if qkv_bias else None)
-            q = b.matmul(x, b.param(("blocks", "wq"), (H, hd), layer=l,
-                                    cols=cq), bias=bq, tag=f"{tag}.h{i}.q")
-            if rope_theta is not None:
-                q = b.rope(q, theta=rope_theta, pos=pos,
-                           tag=f"{tag}.h{i}.q_rope")
-            q_heads.append(q)
-        for s in range(B):
-            stag = f"{tag}.kv{j}.s{s}"
-            rows = [b.slot_select(q, s, tag=f"{stag}.q{gi}")
-                    for gi, q in enumerate(q_heads)]
-            qg = (rows[0] if g == 1
-                  else b.concat(rows, axis=-2, tag=f"{stag}.qstack"))
-            kc, vc = banks[s]
-            qk = b.matmul(qg, kc, transpose_b=True, scale=hd ** -0.5,
-                          tag=f"{stag}.qk")
-            sm = b.softmax(qk, valid_upto=pos_slots[s],
-                           tag=f"{stag}.softmax")
-            av = b.matmul(sm, vc, tag=f"{stag}.av")
-            z_parts[s].append(av if g == 1
-                              else b.reshape(av, (1, g * hd),
-                                             tag=f"{stag}.flatten"))
-    z_slots = [(parts[0] if len(parts) == 1
-                else b.concat(parts, tag=f"{tag}.s{s}.merge_heads"))
-               for s, parts in enumerate(z_parts)]
-    z = b.concat(z_slots, axis=-2, tag=f"{tag}.merge_slots")
-    wo = b.param(("blocks", "wo"), (A * hd, H), layer=l)
-    return b.matmul(z, wo, tag=f"{tag}.attn.out")
-
-
-def _logits_head(b: GraphBuilder, cfg: ModelConfig, x: int) -> int:
-    """Final vocab projection: tied configs reuse the (V, H) embedding
-    table transposed (still MMU-resident), untied use lm_head (H, V)."""
-    V, H = cfg.vocab_size, cfg.d_model
-    if cfg.tie_embeddings or cfg.family == "bert":
-        return b.matmul(x, b.param(("embed",), (V, H)), transpose_b=True,
-                        tag="logits")
-    return b.matmul(x, b.param(("lm_head",), (H, V)), tag="logits")
-
-
-def _decode_inputs(b: GraphBuilder, batch: int):
-    """The decode stream's pos input: a scalar for per-sequence streams, a
-    (B,) vector (plus hoisted per-slot scalar selects for softmax masking)
-    for batched streams."""
-    if batch == 1:
-        return b.input("pos", (), dtype="int32"), None
-    pos = b.input("pos", (batch,), dtype="int32")
-    return pos, [b.slot_select(pos, s, tag=f"pos.s{s}")
-                 for s in range(batch)]
-
-
-def _trace_decode_bert(cfg: ModelConfig, cache_len: int,
-                       layers: Optional[int], include_embed: bool,
-                       batch: int = 1, window: bool = False) -> Graph:
-    """Causal incremental BERT step, mirroring models/bert.decode_step
-    (post-norm blocks, learned positions gathered at `pos`)."""
-    b = GraphBuilder()
-    T, H, A, KV = cache_len, cfg.d_model, cfg.num_heads, cfg.num_kv_heads
-    hd, F = cfg.head_dim, cfg.d_ff
-    L = layers if layers is not None else cfg.num_layers
-    pos, pos_slots = _decode_inputs(b, batch)
-    if include_embed:
-        tokens = b.input("tokens", (batch,), dtype="int32")
-        x = b.embed(tokens, b.param(("embed",), (cfg.vocab_size, H)),
-                    tag="embed.tok")
-        pe = b.embed(pos, b.param(("pos_embed",), (cfg.max_position, H)),
-                     tag="embed.pos")
-        x = b.add(x, pe, tag="embed.pos_add")
-        x = b.add(x, b.param(("type_embed",), (H,), index=0),
-                  tag="embed.type")
-        x = b.layernorm(x, b.param(("ln_embed", "gamma"), (H,)),
-                        b.param(("ln_embed", "beta"), (H,)),
-                        eps=1e-12, tag="embed.ln")
-    else:
-        x = b.input("x", (batch, H))
-    for l in range(L):
-        tag = f"enc{l}"
-        proj = _decode_attention(b, x, l, T=T, H=H, A=A, KV=KV, hd=hd,
-                                 qkv_bias=cfg.qkv_bias, rope_theta=None,
-                                 pos=pos, tag=tag, B=batch,
-                                 pos_slots=pos_slots, window=window)
-        x = _post_norm_rest(b, x, proj, l, H=H, F=F, eps=1e-12,
-                            mlp_bias=cfg.mlp_bias, norm_beta=True, tag=tag)
-    if include_embed:
-        x = _logits_head(b, cfg, x)
-    b.output(x)
-    return b.g
-
-
-def _trace_decode_dense(cfg: ModelConfig, cache_len: int,
-                        layers: Optional[int], include_embed: bool,
-                        batch: int = 1, window: bool = False) -> Graph:
-    """Pre-norm dense decode step, mirroring models/transformer.decode_step
-    (full-attention layers, or ring caches for "sliding" attention when
-    window=True — see trace_decode)."""
-    _check_dense_supported(cfg, window_ok=window)
-    b = GraphBuilder()
-    T, H, A, KV = cache_len, cfg.d_model, cfg.num_heads, cfg.num_kv_heads
-    hd, F = cfg.head_dim, cfg.d_ff
-    L = layers if layers is not None else cfg.num_layers
-    theta = cfg.rope_theta if cfg.rope == "standard" else None
-    pos, pos_slots = _decode_inputs(b, batch)
-    if include_embed:
-        tokens = b.input("tokens", (batch,), dtype="int32")
-        x = b.embed(tokens, b.param(("embed",), (cfg.vocab_size, H)),
-                    tag="embed.tok")
-    else:
-        x = b.input("x", (batch, H))
-    for l in range(L):
-        tag = f"blk{l}"
-        h = _dense_norm(b, cfg, x, ("blocks", "ln1"), l, f"{tag}.ln1")
-        attn = _decode_attention(b, h, l, T=T, H=H, A=A, KV=KV, hd=hd,
-                                 qkv_bias=cfg.qkv_bias, rope_theta=theta,
-                                 pos=pos, tag=tag, B=batch,
-                                 pos_slots=pos_slots, window=window)
-        x = b.add(x, attn, tag=f"{tag}.res_a")
-        h2 = _dense_norm(b, cfg, x, ("blocks", "ln2"), l, f"{tag}.ln2")
-        down = _dense_mlp(b, cfg, h2, l, H=H, F=F, tag=tag)
-        x = b.add(x, down, tag=f"{tag}.res_b")
-    x = _dense_norm(b, cfg, x, ("ln_f",), None, "ln_f")
-    if include_embed:
-        x = _logits_head(b, cfg, x)
-    b.output(x)
-    return b.g
-
-
-_DECODE_TRACERS = {"bert": _trace_decode_bert, "dense": _trace_decode_dense}
+    return _stack(_shape_dims(shape, norm_beta=True),
+                  _Rows("prompt", shape.seq), layers, False, head=False)
 
 
 def trace_decode(cfg: ModelConfig, cache_len: int, *,
@@ -744,14 +575,7 @@ def trace_decode(cfg: ModelConfig, cache_len: int, *,
     serving mode: the smallest bucket that never grows) — identical to
     the full model only while total tokens <= cache_len.
     """
-    tracer = _DECODE_TRACERS.get(cfg.family)
-    if tracer is None:
-        gap = ("MoE decode streams (per-token capacity-1 dispatch)"
-               if cfg.family == "moe"
-               else f"decode streams for family {cfg.family!r}")
-        raise CompileError(
-            f"npec cannot lower {gap} yet ({cfg.name!r}) "
-            "(see ROADMAP.md Open items)")
+    _gate(cfg, "decode", window=window)
     if batch < 1:
         raise ValueError(f"batch must be >= 1, got {batch}")
     if window and cfg.attention == "sliding" and cache_len != cfg.window:
@@ -759,7 +583,9 @@ def trace_decode(cfg: ModelConfig, cache_len: int, *,
             f"windowed decode for {cfg.name!r} needs cache_len == "
             f"cfg.window ({cfg.window}), got {cache_len} — any other ring "
             "capacity diverges from the model's sliding-window mask")
-    return tracer(cfg, cache_len, layers, include_embed, batch, window)
+    return _stack(_dims(cfg), _Rows("decode", batch, T=cache_len,
+                                    window=window),
+                  layers, include_embed, head=include_embed)
 
 
 def trace_prefill(cfg: ModelConfig, seq: int, *,
@@ -802,175 +628,15 @@ def trace_prefill(cfg: ModelConfig, seq: int, *,
             f"windowed prefill for {cfg.name!r} holds at most cfg.window "
             f"({cfg.window}) prompt tokens, got {seq} — longer prompts "
             "need banded prefill tiles (see ROADMAP.md Open items)")
-    if cache_len is not None:
-        if seq > cache_len:
-            raise ValueError(
-                f"prefill slice of {seq} rows exceeds the cache capacity "
-                f"{cache_len}")
-        if cfg.family == "bert":
-            return _trace_prefill_chunk_bert(cfg, seq, cache_len, layers,
-                                             include_embed)
-        if cfg.family == "dense":
-            if not cfg.causal:
-                raise CompileError(
-                    f"npec serving prefill needs a causal model; "
-                    f"{cfg.name!r} is bidirectional")
-            return _trace_prefill_chunk_dense(cfg, seq, cache_len, layers,
-                                              include_embed,
-                                              window_ok=window)
-    elif cfg.family == "bert":
-        return _trace_bert(cfg, seq, layers, include_embed, causal=True,
-                           logits_head=True, export_kv=True)
-    elif cfg.family == "dense":
-        if not cfg.causal:
-            raise CompileError(
-                f"npec serving prefill needs a causal model; {cfg.name!r} "
-                "is bidirectional")
-        return _trace_dense(cfg, seq, layers, include_embed, export_kv=True,
-                            window_ok=window)
-    gap = ("MoE decode streams (per-token capacity-1 dispatch)"
-           if cfg.family == "moe"
-           else f"decode streams for family {cfg.family!r}")
-    raise CompileError(
-        f"npec cannot lower {gap} yet ({cfg.name!r}), so it cannot serve "
-        "this family (see ROADMAP.md Open items)")
-
-
-# ---------------------------------------------------------------------------
-# Chunked-prefill slices: C prompt rows appended into decode cache banks
-# ---------------------------------------------------------------------------
-
-def _chunk_attention(b: GraphBuilder, x: int, l: int, *, T: int, H: int,
-                     A: int, KV: int, hd: int, qkv_bias: bool,
-                     rope_theta: Optional[float], pos_ids: int,
-                     tag: str) -> int:
-    """Causal-slice attention for chunked prefill: C new prompt rows over
-    the decode streams' (T, hd) cache banks; returns the output projection.
-
-    Per kv head: the slice's (C, hd) k/v projections (post-rope at their
-    absolute positions `pos_ids`) burst-append into the cache bank
-    (`cache_append` rows=C, MWU traffic), then each query head runs a
-    (C, T) QK^T over the *updated* bank with a row-masked softmax
-    (row r attends to slots <= pos_ids[r] — same-slice future keys are in
-    the bank but masked, so the slice is causally exact) and the
-    attention-weighted V reduction.  Row r's valid key values are
-    identical to the monolithic causal prefill's row pos_ids[r], which is
-    what makes the chunked path bitwise-equal in float mode.
-    """
-    g = A // KV
-    z_heads = []
-    for j in range(KV):
-        ck = (j * hd, (j + 1) * hd)
-        bk = (b.param(("blocks", "bk"), (hd,), layer=l, cols=ck)
-              if qkv_bias else None)
-        bv = (b.param(("blocks", "bv"), (hd,), layer=l, cols=ck)
-              if qkv_bias else None)
-        k = b.matmul(x, b.param(("blocks", "wk"), (H, hd), layer=l,
-                                cols=ck), bias=bk, tag=f"{tag}.kv{j}.k")
-        if rope_theta is not None:
-            k = b.rope(k, theta=rope_theta, pos=pos_ids,
-                       tag=f"{tag}.kv{j}.k_rope")
-        v = b.matmul(x, b.param(("blocks", "wv"), (H, hd), layer=l,
-                                cols=ck), bias=bv, tag=f"{tag}.kv{j}.v")
-        kc = b.cache(f"{tag}.kv{j}.k", (T, hd))
-        vc = b.cache(f"{tag}.kv{j}.v", (T, hd))
-        kc = b.cache_append(kc, k, pos_ids)
-        vc = b.cache_append(vc, v, pos_ids)
-        for gi in range(g):
-            i = j * g + gi
-            cq = (i * hd, (i + 1) * hd)
-            bq = (b.param(("blocks", "bq"), (hd,), layer=l, cols=cq)
-                  if qkv_bias else None)
-            q = b.matmul(x, b.param(("blocks", "wq"), (H, hd), layer=l,
-                                    cols=cq), bias=bq, tag=f"{tag}.h{i}.q")
-            if rope_theta is not None:
-                q = b.rope(q, theta=rope_theta, pos=pos_ids,
-                           tag=f"{tag}.h{i}.q_rope")
-            qk = b.matmul(q, kc, transpose_b=True, scale=hd ** -0.5,
-                          tag=f"{tag}.h{i}.qk")
-            sm = b.softmax(qk, valid_upto=pos_ids,
-                           tag=f"{tag}.h{i}.softmax")
-            z_heads.append(b.matmul(sm, vc, tag=f"{tag}.h{i}.av"))
-    z = b.concat(z_heads, tag=f"{tag}.merge_heads")
-    wo = b.param(("blocks", "wo"), (A * hd, H), layer=l)
-    return b.matmul(z, wo, tag=f"{tag}.attn.out")
-
-
-def _trace_prefill_chunk_bert(cfg: ModelConfig, rows: int, cache_len: int,
-                              layers: Optional[int],
-                              include_embed: bool) -> Graph:
-    """One causal BERT prefill slice of `rows` prompt tokens over
-    cache banks of capacity `cache_len` (learned positions gathered at
-    `pos_ids`, exactly as the decode step gathers at `pos`)."""
-    b = GraphBuilder()
-    C, T = rows, cache_len
-    H, A, KV = cfg.d_model, cfg.num_heads, cfg.num_kv_heads
-    hd, F = cfg.head_dim, cfg.d_ff
-    L = layers if layers is not None else cfg.num_layers
-    pos_ids = b.input("pos_ids", (C,), dtype="int32")
-    if include_embed:
-        tokens = b.input("tokens", (C,), dtype="int32")
-        x = b.embed(tokens, b.param(("embed",), (cfg.vocab_size, H)),
-                    tag="embed.tok")
-        pe = b.embed(pos_ids, b.param(("pos_embed",),
-                                      (cfg.max_position, H)),
-                     tag="embed.pos")
-        x = b.add(x, pe, tag="embed.pos_add")
-        x = b.add(x, b.param(("type_embed",), (H,), index=0),
-                  tag="embed.type")
-        x = b.layernorm(x, b.param(("ln_embed", "gamma"), (H,)),
-                        b.param(("ln_embed", "beta"), (H,)),
-                        eps=1e-12, tag="embed.ln")
-    else:
-        x = b.input("x", (C, H))
-    for l in range(L):
-        tag = f"enc{l}"
-        proj = _chunk_attention(b, x, l, T=T, H=H, A=A, KV=KV, hd=hd,
-                                qkv_bias=cfg.qkv_bias, rope_theta=None,
-                                pos_ids=pos_ids, tag=tag)
-        x = _post_norm_rest(b, x, proj, l, H=H, F=F, eps=1e-12,
-                            mlp_bias=cfg.mlp_bias, norm_beta=True, tag=tag)
-    if include_embed:
-        x = _logits_head(b, cfg, x)
-    b.output(x)
-    return b.g
-
-
-def _trace_prefill_chunk_dense(cfg: ModelConfig, rows: int, cache_len: int,
-                               layers: Optional[int],
-                               include_embed: bool, *,
-                               window_ok: bool = False) -> Graph:
-    """One causal dense prefill slice of `rows` prompt tokens over cache
-    banks of capacity `cache_len` (RoPE rotated at `pos_ids`)."""
-    _check_dense_supported(cfg, window_ok=window_ok)
-    b = GraphBuilder()
-    C, T = rows, cache_len
-    H, A, KV = cfg.d_model, cfg.num_heads, cfg.num_kv_heads
-    hd, F = cfg.head_dim, cfg.d_ff
-    L = layers if layers is not None else cfg.num_layers
-    theta = cfg.rope_theta if cfg.rope == "standard" else None
-    pos_ids = b.input("pos_ids", (C,), dtype="int32")
-    if include_embed:
-        tokens = b.input("tokens", (C,), dtype="int32")
-        x = b.embed(tokens, b.param(("embed",), (cfg.vocab_size, H)),
-                    tag="embed.tok")
-    else:
-        x = b.input("x", (C, H))
-    for l in range(L):
-        tag = f"blk{l}"
-        h = _dense_norm(b, cfg, x, ("blocks", "ln1"), l, f"{tag}.ln1")
-        attn = _chunk_attention(b, h, l, T=T, H=H, A=A, KV=KV, hd=hd,
-                                qkv_bias=cfg.qkv_bias, rope_theta=theta,
-                                pos_ids=pos_ids, tag=tag)
-        x = b.add(x, attn, tag=f"{tag}.res_a")
-        h2 = _dense_norm(b, cfg, x, ("blocks", "ln2"), l, f"{tag}.ln2")
-        down = _dense_mlp(b, cfg, h2, l, H=H, F=F, tag=tag)
-        x = b.add(x, down, tag=f"{tag}.res_b")
-    x = _dense_norm(b, cfg, x, ("ln_f",), None, "ln_f")
-    if include_embed:
-        x = _logits_head(b, cfg, x)
-    b.output(x)
-    return b.g
+    if cache_len is not None and seq > cache_len:
+        raise ValueError(
+            f"prefill slice of {seq} rows exceeds the cache capacity "
+            f"{cache_len}")
+    _gate(cfg, "prefill", window=window)
+    rows = (_Rows("prompt", seq, causal=True, export_kv=True)
+            if cache_len is None else _Rows("slice", seq, T=cache_len))
+    return _stack(_dims(cfg), rows, layers, include_embed,
+                  head=include_embed)
 
 
 def trace_prefill_slice_shape(shape, cache_len: int, rows: int, *,
@@ -980,20 +646,8 @@ def trace_prefill_slice_shape(shape, cache_len: int, rows: int, *,
     uses to cost the per-chunk stall bound (no ModelConfig, no biases, no
     embedding/logits head; per-layer streams are identical, so cycle
     totals scale linearly in layer count)."""
-    b = GraphBuilder()
-    pos_ids = b.input("pos_ids", (rows,), dtype="int32")
-    x = b.input("x", (rows, shape.hidden))
-    for l in range(layers):
-        tag = f"enc{l}"
-        proj = _chunk_attention(b, x, l, T=cache_len, H=shape.hidden,
-                                A=shape.heads, KV=shape.heads,
-                                hd=shape.head_dim, qkv_bias=False,
-                                rope_theta=None, pos_ids=pos_ids, tag=tag)
-        x = _post_norm_rest(b, x, proj, l, H=shape.hidden, F=shape.d_ff,
-                            eps=1e-12, mlp_bias=False, norm_beta=False,
-                            tag=tag)
-    b.output(x)
-    return b.g
+    return _stack(_shape_dims(shape), _Rows("slice", rows, T=cache_len),
+                  layers, False, head=False)
 
 
 def trace_decode_bert_shape(shape, cache_len: int, *, layers: int = 1,
@@ -1004,22 +658,9 @@ def trace_decode_bert_shape(shape, cache_len: int, *, layers: int = 1,
     identical, so cycle totals scale linearly in layer count).  batch=B
     emits the merged B-slot stream (core.cycles.batched_decode_step_cycles).
     """
-    b = GraphBuilder()
-    pos, pos_slots = _decode_inputs(b, batch)
-    x = b.input("x", (batch, shape.hidden))
-    for l in range(layers):
-        tag = f"enc{l}"
-        proj = _decode_attention(b, x, l, T=cache_len, H=shape.hidden,
-                                 A=shape.heads, KV=shape.heads,
-                                 hd=shape.head_dim, qkv_bias=False,
-                                 rope_theta=None, pos=pos, tag=tag,
-                                 B=batch, pos_slots=pos_slots,
-                                 window=window)
-        x = _post_norm_rest(b, x, proj, l, H=shape.hidden, F=shape.d_ff,
-                            eps=1e-12, mlp_bias=False, norm_beta=False,
-                            tag=tag)
-    b.output(x)
-    return b.g
+    return _stack(_shape_dims(shape), _Rows("decode", batch, T=cache_len,
+                                            window=window),
+                  layers, False, head=False)
 
 
 # ---------------------------------------------------------------------------
@@ -1189,7 +830,7 @@ def main(argv=None) -> None:
             _check_bert(args)
         if cfg.family == "moe" and not args.decode:
             _check_moe(args)
-        if cfg.family in _DECODE_TRACERS:
+        if cfg.family in _SERVING_FAMILIES:
             _check_decode(args)
         print("npec check OK")
 
