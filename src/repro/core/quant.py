@@ -55,6 +55,20 @@ def quantize(x: jnp.ndarray, bits: int = 8,
     else:
         red = tuple(i for i in range(x.ndim) if i != (axis % x.ndim))
         amax = jnp.max(jnp.abs(xf), axis=red, keepdims=True)
+    return _from_amax(xf, amax, bits)
+
+
+def quantize_weight(w: jnp.ndarray, bits: int = 8) -> QTensor:
+    """A (..., K, N) weight with one scale per output column, reduced
+    over K alone: a stacked (L, K, N) weight gets (L, 1, N) scales, so
+    slicing its layers or columns gives, bitwise, what quantizing each
+    slice on its own gives."""
+    xf = w.astype(jnp.float32)
+    return _from_amax(xf, jnp.max(jnp.abs(xf), axis=-2, keepdims=True),
+                      bits)
+
+
+def _from_amax(xf: jnp.ndarray, amax: jnp.ndarray, bits: int) -> QTensor:
     qmax = float(2 ** (bits - 1) - 1)
     scale = jnp.maximum(amax, 1e-12) / qmax
     q = jnp.clip(jnp.round(xf / scale), -qmax - 1, qmax).astype(_qdtype(bits))
@@ -95,16 +109,18 @@ def quant_dense(x: jnp.ndarray, w: QTensor, bias: Optional[jnp.ndarray] = None,
     return out.astype(dt)
 
 
-def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray,
+def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray | QTensor,
                       bias: Optional[jnp.ndarray] = None,
                       npe_quant: bool = False, bits: int = 8) -> jnp.ndarray:
     """Dense layer that routes through the MMU when the NPE mode is on.
 
     `w` is kept in float master form (training still works); quantization is
     applied functionally, matching the paper's post-training quantization
-    flow ([28] Q8BERT-style symmetric).  Lead axes are flattened to rows
-    and each row is quantized on its own, so the result of a stacked
-    (R, K) input equals that of R one-row calls, bitwise.
+    flow ([28] Q8BERT-style symmetric).  An 8-bit weight may come already
+    quantized (a `QTensor` from `quantize_weight`, prepared once), and is
+    then used as it is.  Lead axes are flattened to rows and each row is
+    quantized on its own, so the result of a stacked (R, K) input equals
+    that of R one-row calls, bitwise.
     """
     if not npe_quant:
         return x @ w if bias is None else x @ w + bias
@@ -112,7 +128,7 @@ def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray,
     x2 = x.reshape(-1, k)
     if bits == 8:
         # True integer path: int8 x int8 -> int32 is exact for K <= 2^17.
-        wq = quantize(w, bits, axis=1)
+        wq = w if isinstance(w, QTensor) else quantize_weight(w, bits)
         y = quant_dense(x2, wq, bias, act_bits=bits)
     else:
         # 16-bit MMU mode.  int16 products overflow int32 accumulators and
@@ -126,4 +142,4 @@ def dense_maybe_quant(x: jnp.ndarray, w: jnp.ndarray,
         if bias is not None:
             y = y + bias.astype(jnp.float32)
         y = y.astype(x.dtype)
-    return y.reshape(*lead, w.shape[1])
+    return y.reshape(*lead, y.shape[-1])

@@ -49,6 +49,8 @@ Entry points:
                                         carries KV-cache state across
                                         steps; batched-slot streams get
                                         per-slot pos/reset/load_slot).
+    prepare_weights                     quantize a tree's MMU weights
+                                        once, for every later run.
 
 The serving layer over all of this lives in repro.npec.runtime
 (`NPEEngine`: continuous batching + cycle-clocked latency; docs/serving.md),
@@ -80,7 +82,8 @@ from repro.npec.trace import (CompileError, moe_capacity, trace_bert_shape,
                               trace_decode, trace_decode_bert_shape,
                               trace_model, trace_moe_block, trace_prefill,
                               trace_prefill_slice_shape)
-from repro.npec.exec import DecodeSession, ExecResult, execute
+from repro.npec.exec import (DecodeSession, ExecResult, execute,
+                             prepare_weights)
 
 
 def compile_model(cfg: ModelConfig, seq: int, hw: Optional[NPEHardware] = None,

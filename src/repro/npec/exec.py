@@ -38,6 +38,15 @@ MMEM has to cover (paper §5.2).  That bookkeeping reads only shapes and
 dtypes, so it runs at trace time and each call returns what its feed
 shapes gave.
 
+With the 8-bit MMU each weight is quantized once, not inside every
+call: `prepare_weights` adds an int8 `QTensor` (per-column scales) for
+each weight leaf the graph's matmuls quantize, beside the registry's
+leaves, and a program slices it by layer and columns.  `NPEEngine`
+prepares its tree when it is built; a program handed a raw tree
+prepares it inside itself with the same function, so both give the
+same bits.  An `embed` gathers rows from its table in the stored dtype
+and casts only those rows.
+
 Each call and each `DecodeSession` bank update opens a wall-clock span
 (repro.npec.obs.spans); each trace opens `npec.exec.trace`.  While a
 profiler records, each call also marks its nodes, after the dispatch:
@@ -68,14 +77,15 @@ import numpy as np
 
 from repro.config import ModelConfig
 from repro.core import nvu
-from repro.core.quant import dense_maybe_quant
+from repro.core.quant import QTensor, dense_maybe_quant, quantize_weight
 from repro.models import common as cm
 from repro.npec.ir import FOLDED_OPS, Graph, Node
 from repro.npec.lower import CompiledProgram
 from repro.npec.obs.spans import (EXEC_EXECUTE, EXEC_PREFIX,
-                                  EXEC_QUANTIZE_WEIGHT, EXEC_TRACE,
-                                  SESSION_LOAD_SLOT, SESSION_MIGRATE,
-                                  SESSION_RESET_SLOT, node_class, span)
+                                  EXEC_PREPARE_WEIGHTS, EXEC_QUANTIZE_WEIGHT,
+                                  EXEC_TRACE, SESSION_LOAD_SLOT,
+                                  SESSION_MIGRATE, SESSION_RESET_SLOT,
+                                  node_class, span)
 
 
 @dataclass
@@ -103,6 +113,7 @@ def _param_leaf(params, node: Node):
 
 
 def _slice_param(v, node: Node) -> jnp.ndarray:
+    """The node's slice of a float leaf, in the leaf's stored dtype."""
     if node.attrs.get("layer") is not None:
         v = v[node.attrs["layer"]]
     if node.attrs.get("index") is not None:
@@ -113,7 +124,106 @@ def _slice_param(v, node: Node) -> jnp.ndarray:
     if node.attrs.get("cols") is not None:
         c0, c1 = node.attrs["cols"]
         v = v[..., c0:c1]
-    return jnp.asarray(v, jnp.float32)
+    return jnp.asarray(v)
+
+
+def _slice_weight(w: QTensor, node: Node, transpose: bool) -> QTensor:
+    """The node's slice of a prepared weight, stored (..., K, N) as its
+    matmul reads it: the layer or expert, then output columns, which
+    carry their scales along.  A slice along K would change the scales,
+    and no graph makes one."""
+    k_cut, n_cut = ("cols", "rows") if transpose else ("rows", "cols")
+    if node.attrs.get(k_cut) is not None:
+        raise ValueError(f"param {node.attrs['path']} is sliced along the "
+                         "contraction axis of its prepared weight")
+    q, scale = w
+    for axis in ("layer", "index"):
+        if node.attrs.get(axis) is not None:
+            q, scale = q[node.attrs[axis]], scale[node.attrs[axis]]
+    if node.attrs.get(n_cut) is not None:
+        c0, c1 = node.attrs[n_cut]
+        q, scale = q[..., c0:c1], scale[..., c0:c1]
+    return QTensor(q, scale)
+
+
+# ---------------------------------------------------------------------------
+# Weight preparation: each MMU weight quantized once, not on every call
+# ---------------------------------------------------------------------------
+
+# Key of a prepared tree's int8 weights, beside the registry's leaves.
+PREPARED = "npe_mmu_int8"
+_TABLE = "table"             # role of a param that only an `embed` gathers
+
+
+def _weight_name(path: Tuple[str, ...], transpose: bool) -> str:
+    return "/".join(path) + (".T" if transpose else "")
+
+
+def _param_roles(graph: Graph, *, npe_quant: bool,
+                 bits: int) -> Dict[int, Any]:
+    """The params whose value is not their float32 slice: a table that
+    only `embed` ops gather from stays in its stored dtype (`_TABLE`),
+    and with the 8-bit MMU a weight that only quantizable weight-resident
+    matmuls read is its prepared int8 slice, named by
+    (`_weight_name`, transposed).  Other params are float32 slices."""
+    uses: Dict[int, List[Any]] = {n.id: [] for n in graph.nodes
+                                  if n.op == "param"}
+    for n in graph.nodes:
+        for slot, i in enumerate(n.inputs):
+            if i not in uses:
+                continue
+            if n.op == "embed" and slot == 1:
+                uses[i].append(_TABLE)
+            elif (n.op == "matmul" and slot == 1 and npe_quant and bits == 8
+                  and n.attrs.get("quantize", True)):
+                uses[i].append(bool(n.attrs.get("transpose_b")))
+            else:
+                uses[i].append(None)
+    roles: Dict[int, Any] = {}
+    for nid, use in uses.items():
+        if not use or len(set(use)) != 1 or use[0] is None:
+            continue
+        if use[0] == _TABLE:
+            roles[nid] = _TABLE
+        else:
+            roles[nid] = (_weight_name(graph.node(nid).attrs["path"],
+                                       use[0]), use[0])
+    return roles
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _prepare_weight(w, transpose: bool) -> QTensor:
+    """One stacked leaf as the MMU reads it, per layer and output column."""
+    return quantize_weight(jnp.swapaxes(w, -1, -2) if transpose else w, 8)
+
+
+def prepare_weights(params: Any, graph: Graph, *,
+                    cfg: Optional[ModelConfig] = None,
+                    npe_quant: bool = False, bits: int = 8) -> Any:
+    """The tree `graph`'s program takes under these numerics: `params`
+    (the registry tree, whose float leaves stay) and, with the 8-bit MMU,
+    each weight leaf that `graph`'s matmuls quantize as an int8 `QTensor`
+    under `params[PREPARED]`.  Weights already there are kept, so a
+    prepared tree passes through unchanged, and a program handed a raw
+    tree prepares it inside itself the same way.  Each preparation opens
+    `npec.exec.prepare_weights`; numerics follow `cfg` when given."""
+    if cfg is not None:
+        npe_quant, bits = cfg.npe_quant, cfg.npe_quant_bits
+    roles = _param_roles(graph, npe_quant=npe_quant, bits=bits)
+    have = params.get(PREPARED, {}) if roles else {}
+    need = {}
+    for nid, role in roles.items():
+        if role != _TABLE and role[0] not in have:
+            need[role[0]] = (_param_leaf(params, graph.node(nid)), role[1])
+    if not need:
+        return params
+    made = {name: jax.eval_shape(_prepare_weight, w, transpose)
+            for name, (w, transpose) in need.items()}
+    with span(EXEC_PREPARE_WEIGHTS, leaves=len(made),
+              bytes=sum(map(_nbytes, made.values()))):
+        made = {name: _prepare_weight(w, transpose)
+                for name, (w, transpose) in need.items()}
+    return {**params, PREPARED: {**have, **made}}
 
 
 def _matmul(node: Node, a, b, bias, *, weight_resident: bool,
@@ -126,8 +236,11 @@ def _matmul(node: Node, a, b, bias, *, weight_resident: bool,
     if weight_resident:
         # MMU-resident weight (quantizable); a transposed resident weight
         # (the tied-embedding logits head) is stored transposed, exactly as
-        # models/common.logits_out feeds embed.T to the quantized dense
-        w = jnp.swapaxes(b, -1, -2) if node.attrs.get("transpose_b") else b
+        # models/common.logits_out feeds embed.T to the quantized dense.
+        # A prepared int8 weight comes in the matmul's orientation.
+        w = (jnp.swapaxes(b, -1, -2)
+             if node.attrs.get("transpose_b") and not isinstance(b, QTensor)
+             else b)
         y = dense_maybe_quant(a, w, None, npe_quant=npe_quant, bits=bits)
     elif node.attrs.get("transpose_b"):
         y = jnp.einsum("...ik,...jk->...ij", a, b,
@@ -355,7 +468,7 @@ _SHARED_OPS = frozenset(("matmul", "softmax", "layernorm", "rmsnorm", "act",
 
 
 def _nbytes(x) -> int:
-    return int(x.size) * x.dtype.itemsize
+    return sum(int(v.size) * v.dtype.itemsize for v in jax.tree.leaves(x))
 
 
 def _use_counts(graph: Graph) -> Dict[int, int]:
@@ -384,6 +497,7 @@ def _interpret(graph: Graph, params: Any, feeds: Dict[str, Any], *,
     peak = 0
     mask_memo: Dict[Any, jnp.ndarray] = {}   # per-trace dispatch-mask cache
     uses = _use_counts(graph)
+    roles = _param_roles(graph, npe_quant=npe_quant, bits=bits)
 
     def put(nid: int, val):
         nonlocal live, peak
@@ -412,7 +526,15 @@ def _interpret(graph: Graph, params: Any, feeds: Dict[str, Any], *,
                            wres, npe_quant, bits, use_pwl, segments)
             put(node.id, _shared_node(key, *vals))
         elif op == "param":
-            put(node.id, _slice_param(_param_leaf(params, node), node))
+            role = roles.get(node.id)
+            if role is None:
+                put(node.id, _slice_param(_param_leaf(params, node),
+                                          node).astype(jnp.float32))
+            elif role == _TABLE:
+                put(node.id, _slice_param(_param_leaf(params, node), node))
+            else:
+                put(node.id, _slice_weight(params[PREPARED][role[0]], node,
+                                           role[1]))
         elif op == "input":
             x = jnp.asarray(feeds[node.attrs["name"]])
             put(node.id, x if node.dtype == "int32"
@@ -430,8 +552,10 @@ def _interpret(graph: Graph, params: Any, feeds: Dict[str, Any], *,
             lead = x.shape[:x.ndim - len(src)]   # preserved batch axes
             put(node.id, x.reshape(lead + node.shape))
         elif op == "embed":
+            # gathered in the table's stored dtype, the rows cast after:
+            # bitwise the gather from a float32 copy of the whole table
             tokens, table = get(node.inputs[0]), get(node.inputs[1])
-            put(node.id, jnp.take(table, tokens, axis=0))
+            put(node.id, jnp.take(table, tokens, axis=0).astype(jnp.float32))
         elif op == "cache":
             put(node.id, jnp.asarray(feeds[node.attrs["name"]],
                                      jnp.float32))
@@ -529,6 +653,8 @@ def executable(graph: Graph, *, npe_quant: bool, bits: int,
 
         def program(params, feeds):
             with span(EXEC_TRACE, nodes=len(graph.nodes)):
+                params = prepare_weights(params, graph, npe_quant=npe_quant,
+                                         bits=bits)
                 *res, peak = _interpret(graph, params, feeds,
                                         npe_quant=npe_quant, bits=bits,
                                         use_pwl=use_pwl, segments=segments)

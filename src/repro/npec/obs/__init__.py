@@ -25,7 +25,8 @@ from repro.npec.obs.schema import (ATTR_CATEGORY, METRIC_COUNTERS,
                                    STREAM_KINDS, validate_trace)
 from repro.npec.obs.spans import (ENGINE_ADMIT, ENGINE_DECODE, ENGINE_SYNC,
                                   EXEC_EXECUTE, EXEC_PREFIX,
-                                  EXEC_QUANTIZE_WEIGHT, EXEC_TRACE,
+                                  EXEC_PREPARE_WEIGHTS, EXEC_QUANTIZE_WEIGHT,
+                                  EXEC_TRACE,
                                   OP_CLASS, SESSION_LOAD_SLOT,
                                   SESSION_MIGRATE, SESSION_RESET_SLOT,
                                   node_class, span)
@@ -34,7 +35,8 @@ from repro.npec.obs.tracer import NULL_TRACER, NullTracer, Tracer, UNITS
 __all__ = [
     "ATTR_CATEGORY", "Counter", "CycleHistogram",
     "ENGINE_ADMIT", "ENGINE_DECODE", "ENGINE_SYNC", "EXEC_EXECUTE",
-    "EXEC_PREFIX", "EXEC_QUANTIZE_WEIGHT", "EXEC_TRACE", "METRIC_COUNTERS",
+    "EXEC_PREFIX", "EXEC_PREPARE_WEIGHTS", "EXEC_QUANTIZE_WEIGHT",
+    "EXEC_TRACE", "METRIC_COUNTERS",
     "METRIC_FAMILIES", "METRIC_HISTOGRAMS", "MetricsRegistry",
     "NULL_TRACER", "NullTracer", "OP_CLASS", "REQUEST_INSTANTS",
     "REQUEST_SPANS", "SESSION_LOAD_SLOT", "SESSION_MIGRATE",

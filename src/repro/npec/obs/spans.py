@@ -14,10 +14,14 @@ The executor runs each graph as one jitted program, so a node has no
 host work of its own to time.  Each execution instead marks its nodes:
 while a profiler records, `exec.execute` opens one empty
 `npec.exec.<class>` span per graph node, in graph order, with an
-`npec.exec.quantize_weight` inside each matmul that quantizes its
-weight, right after it dispatches the program.  Their counts are the
-nodes each execution ran; their length is the host's per-node cost,
-which is nothing.  `npec.exec.trace` opens around each trace of a
+`npec.exec.quantize_weight` inside each matmul that runs on a
+quantized weight (with the 8-bit MMU an int8 weight, prepared once),
+right after it dispatches the program.  Their
+counts are the nodes each execution ran; their length is the host's
+per-node cost, which is nothing.  The preparation itself opens
+`npec.exec.prepare_weights`: once when an engine is built, or while JAX
+traces a program handed raw weights, and never in a warm serving loop.
+`npec.exec.trace` opens around each trace of a
 program by JAX, a graph's or the session's slot writer's, so its count
 in a window is the number of executor retraces.  In the compiled program each node's device ops carry the
 node's class as a `jax.named_scope`.
@@ -42,6 +46,7 @@ EXEC_TRACE = "npec.exec.trace"              # nodes, or a slot write's rows and
                                             # banks; opens only as JAX traces
 EXEC_PREFIX = "npec.exec."
 EXEC_QUANTIZE_WEIGHT = "npec.exec.quantize_weight"  # inside an `mmu` mark
+EXEC_PREPARE_WEIGHTS = "npec.exec.prepare_weights"  # leaves, bytes
 
 # IR op -> the class its node mark is named by (`npec.exec.<class>`), and
 # the `jax.named_scope` its device ops carry in the compiled program.  A

@@ -65,7 +65,8 @@ from repro.config import ModelConfig
 from repro.core.overlay import NPEHardware
 from repro.npec import (CompiledProgram, DecodeSession, compile_decode,
                         compile_prefill, execute, greedy_schedule,
-                        schedule_for, stream_schedule, transfer_cycles)
+                        prepare_weights, schedule_for, stream_schedule,
+                        transfer_cycles)
 from repro.npec.obs.metrics import MetricsRegistry
 from repro.npec.obs.spans import (ENGINE_ADMIT, ENGINE_DECODE, ENGINE_SYNC,
                                   span)
@@ -383,9 +384,14 @@ class NPEEngine:
 
         self.numeric = params is not None
         self._npe_cfg = (cfg.with_npe(quant_bits=bits) if npe else None)
-        self.params = params
+        self.params = params            # the registry tree, as given
+        # what every program runs on: with the 8-bit MMU each weight is
+        # quantized here, once, instead of inside every execution
+        self._weights = (prepare_weights(params, self.decode_prog.graph,
+                                         cfg=self._npe_cfg)
+                         if self.numeric else None)
         self.session = (DecodeSession(self._decode_progs[self._bucket],
-                                      params, cfg=self._npe_cfg)
+                                      self._weights, cfg=self._npe_cfg)
                         if self.numeric else None)
 
         self.clock = CycleClock(self.hw.clock_hz)
@@ -587,7 +593,7 @@ class NPEEngine:
                             self.trace_overlay, rows=len(req.prompt))
         self._ensure_bucket(len(req.prompt))   # load needs S rows per bank
         if self.numeric:
-            res = execute(prog, self.params, {"tokens": req.prompt},
+            res = execute(prog, self._weights, {"tokens": req.prompt},
                           cfg=self._npe_cfg)
             self.session.load_slot(slot, res.kv_exports, len(req.prompt))
             with span(ENGINE_SYNC):
@@ -684,7 +690,7 @@ class NPEEngine:
             feeds: Dict[str, Any] = dict(st.caches)
             feeds["pos_ids"] = np.arange(base, base + rows, dtype=np.int32)
             feeds["tokens"] = st.req.prompt[base:base + rows]
-            res = execute(prog, self.params, feeds, cfg=self._npe_cfg)
+            res = execute(prog, self._weights, feeds, cfg=self._npe_cfg)
             with span(ENGINE_SYNC):
                 st.caches.update({k: np.asarray(v)
                                   for k, v in res.cache_updates.items()})

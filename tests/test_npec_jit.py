@@ -6,7 +6,8 @@ Seven gates:
     engine sharing the stream cache traces nothing;
   * weights are arguments — the lowered program holds no large constant;
   * the trace-time bookkeeping — `peak_live_bytes` and `n_instrs` equal
-    what the op-by-op interpreter reported for the same graphs;
+    what the op-by-op interpreter reported for the same graphs (with
+    the 8-bit MMU, a weight counted as its prepared int8 slice);
   * numerics — the jitted program's outputs match the same node loop
     run op by op (`jax.disable_jit`), float and NPE mode;
   * causality — an NPE-mode prefill row's logits do not see the prompt
@@ -127,6 +128,10 @@ def test_weights_are_arguments_not_constants(npe):
 # peak_live_bytes and n_instrs of the op-by-op interpreter before the
 # executor was jitted, on the same graphs and feeds
 GOLDEN = {"bert_base": (262400, 53), "glm4_9b": (295936, 68)}
+# with the 8-bit MMU a weight's value is its prepared int8 slice and
+# scales: glm4's peak, the float32 head, falls to the embedding's table
+# (bert's peak was its table already)
+GOLDEN_INT8 = dict(GOLDEN, glm4_9b=(262168, 68))
 
 
 @pytest.mark.parametrize("arch", sorted(GOLDEN))
@@ -149,7 +154,8 @@ def test_trace_time_bookkeeping_is_exact(arch, npe):
                  for p in (3, 4)]
     for f in feeds:                     # traced, then the cached program
         res = npec.execute(prog, params, f, cfg=ncfg)
-        assert (res.peak_live_bytes, res.n_instrs) == GOLDEN[arch]
+        golden = GOLDEN_INT8 if npe else GOLDEN
+        assert (res.peak_live_bytes, res.n_instrs) == golden[arch]
 
 
 def _run(arch, mode):
